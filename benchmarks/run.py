@@ -90,6 +90,8 @@ def main() -> None:
                     help="record the run and write manifest + metrics + "
                          "events + BENCH_<module>.json under DIR")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     common.set_seed(args.seed)
     modules = select_modules(args.only)
 

@@ -23,7 +23,7 @@ from repro.core.workload import request_timing
 from repro.data.pipeline import DataConfig, SyntheticTokenPipeline, device_put_batch
 from repro.experiments import get_scenario, run_experiment
 from repro.launch.inputs import make_rules
-from repro.launch.mesh import make_local_mesh, set_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.serve import ServeEngine
 from repro.launch.steps import build_train_step
 from repro.models import model as model_mod
@@ -38,13 +38,13 @@ shape = ShapeConfig("quickstart", 64, 4, "train")
 rules = make_rules(cfg, shape, mesh)
 opt = make_optimizer(cfg.optimizer)
 pspecs = model_mod.model_specs(cfg, 1)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     state = {"params": init_params(pspecs, jax.random.key(0)),
              "opt": init_params(opt.init_specs(pspecs), jax.random.key(1))}
 pipe = SyntheticTokenPipeline(cfg, DataConfig(4, 64))
 step = jax.jit(build_train_step(cfg, mesh, rules, opt))
 losses = []
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     for i in range(10):
         state, metrics = step(state, device_put_batch(pipe.batch_at(i), mesh, rules))
         losses.append(float(metrics["loss"]))

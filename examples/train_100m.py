@@ -21,7 +21,7 @@ from repro.checkpoint import checkpointer
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, SyntheticTokenPipeline, device_put_batch
 from repro.launch.inputs import make_rules
-from repro.launch.mesh import make_local_mesh, set_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import build_train_step
 from repro.models import model as model_mod
 from repro.models.config import ShapeConfig
@@ -53,7 +53,7 @@ def main():
     rules = make_rules(cfg, shape, mesh)
     opt = make_optimizer(cfg.optimizer, lr=1e-3)
     pspecs = model_mod.model_specs(cfg, mesh.shape["model"])
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = {"params": init_params(pspecs, jax.random.key(0)),
                  "opt": init_params(opt.init_specs(pspecs), jax.random.key(1))}
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(state["params"]))
@@ -69,7 +69,7 @@ def main():
     jit_step = jax.jit(build_train_step(cfg, mesh, rules, opt))
 
     def step_fn(st, b):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             st, m = jit_step(st, b)
         return st, {k: float(v) for k, v in m.items()}
 

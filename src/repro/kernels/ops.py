@@ -43,10 +43,10 @@ def decode_attention(q, k, v, valid_len, *, softcap=0.0,
 
 @partial(jax.jit, static_argnames=("consts", "oob_ticks", "brake_ticks",
                                    "ring_depth", "esc", "block_members",
-                                   "interpret"))
+                                   "block_ticks", "interpret"))
 def polca_tick(occ, bscale, row_budget, *, consts, oob_ticks, brake_ticks,
                ring_depth, esc, block_members=_tick.DEFAULT_BLOCK_MEMBERS,
-               interpret=None):
+               block_ticks=_tick.DEFAULT_BLOCK_TICKS, interpret=None):
     """Non-predictive POLCA tick loop (power fold + latch/ring update) as a
     Pallas kernel. ``consts`` is a hashable :class:`~repro.kernels.tick.
     TickConsts` — per-scenario scalars are compile-time here (the scan
@@ -55,4 +55,5 @@ def polca_tick(occ, bscale, row_budget, *, consts, oob_ticks, brake_ticks,
     return _tick.polca_tick_loop(
         occ, bscale, row_budget, consts, oob_ticks=oob_ticks,
         brake_ticks=brake_ticks, ring_depth=ring_depth, esc=esc,
-        block_members=block_members, interpret=_auto_interpret(interpret))
+        block_members=block_members, block_ticks=block_ticks,
+        interpret=_auto_interpret(interpret))

@@ -116,7 +116,8 @@ def polca_tick_reference(occ, bscale, row_budget, consts, *, oob_ticks,
         return carry, (rw, fire, carry[0], carry[1])
 
     xs = (jnp.arange(T, dtype=jnp.int32), jnp.moveaxis(occ, 1, 0), bscale)
-    final, (rw, fire, f_lp, f_hp) = jax.lax.scan(step, init, xs)
+    _, (rw, fire, f_lp, f_hp) = jax.lax.scan(step, init, xs)
     return dict(row_w=jnp.moveaxis(rw, 0, 1), fire=jnp.moveaxis(fire, 0, 1),
                 f_lp=jnp.moveaxis(f_lp, 0, 1),
-                f_hp=jnp.moveaxis(f_hp, 0, 1), n_brakes=final[4])
+                f_hp=jnp.moveaxis(f_hp, 0, 1),
+                n_brakes=fire.sum(axis=0, dtype=jnp.int32))

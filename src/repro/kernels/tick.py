@@ -37,6 +37,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK_MEMBERS = 8
+# ticks per grid step: each [TB, C, R] plane block is TB (8, 128) tiles
+DEFAULT_BLOCK_TICKS = 256
 
 
 class TickConsts(NamedTuple):
@@ -140,17 +142,32 @@ def polca_latch_step(latches: PolcaLatches, p_obs, p_raw, lp_frac, c, *,
             fire, lp_cmd, hp_cmd)
 
 
+def _ring_get(ring, slot):
+    """``ring[slot]`` as a select chain over the ``D`` static slots: Mosaic
+    cannot lower a dynamic slice of a loop-carried value, and a select is
+    exact, so the scan reference and the kernel read identical bits."""
+    out = ring[0]
+    for d in range(1, ring.shape[0]):
+        out = jnp.where(slot == d, ring[d], out)
+    return out
+
+
+def _ring_set(ring, slot, val):
+    """``ring.at[slot].set(val)`` as a per-slot select (see :func:`_ring_get`)."""
+    return jnp.stack([jnp.where(slot == d, val, ring[d])
+                      for d in range(ring.shape[0])])
+
+
 def apply_ring_tick(ring, f_lp, f_hp, k, *, ring_depth: int):
     """Pop the actuation ring at tick k: apply any due command per frequency
     field, clear the slot. ``ring`` is ``[D, 2, ...]`` (NaN = no command).
     Returns ``(ring', f_lp', f_hp')``."""
     slot = k % ring_depth
-    pend = lax.dynamic_index_in_dim(ring, slot, axis=0, keepdims=False)
+    pend = _ring_get(ring, slot)
     has = ~jnp.isnan(pend)
     f_lp = jnp.where(has[0], pend[0], f_lp)
     f_hp = jnp.where(has[1], pend[1], f_hp)
-    ring = lax.dynamic_update_index_in_dim(
-        ring, jnp.full(ring.shape[1:], jnp.nan, ring.dtype), slot, axis=0)
+    ring = _ring_set(ring, slot, jnp.full(ring.shape[1:], jnp.nan, ring.dtype))
     return ring, f_lp, f_hp
 
 
@@ -162,16 +179,15 @@ def push_ring_commands(ring, fire, lp_cmd, hp_cmd, brake_freq, k, *,
     D = ring_depth
     s_oob = (k + oob_ticks) % D
     s_brk = (k + brake_ticks) % D
-    oob_slot = lax.dynamic_index_in_dim(ring, s_oob, axis=0, keepdims=False)
+    oob_slot = _ring_get(ring, s_oob)
     oob_slot = jnp.stack([
         jnp.where(jnp.isnan(lp_cmd), oob_slot[0], lp_cmd),
         jnp.where(jnp.isnan(hp_cmd), oob_slot[1], hp_cmd)], axis=0)
-    ring = lax.dynamic_update_index_in_dim(ring, oob_slot, s_oob, axis=0)
-    brk_slot = lax.dynamic_index_in_dim(ring, s_brk, axis=0, keepdims=False)
+    ring = _ring_set(ring, s_oob, oob_slot)
+    brk_slot = _ring_get(ring, s_brk)
     brk_val = jnp.where(fire[None], jnp.full_like(brk_slot, brake_freq),
                         brk_slot)
-    ring = lax.dynamic_update_index_in_dim(ring, brk_val, s_brk, axis=0)
-    return ring
+    return _ring_set(ring, s_brk, brk_val)
 
 
 def _tick_init(C: int, R: int, D: int, dtype):
@@ -182,8 +198,7 @@ def _tick_init(C: int, R: int, D: int, dtype):
         t1c=jnp.zeros((C, R), bool), t2c=jnp.zeros((C, R), bool),
         hpc=jnp.zeros((C, R), bool), brk=jnp.zeros((C, R), bool),
         t2s=jnp.zeros((C, R), jnp.int32))
-    nbr = jnp.zeros((C, R), jnp.int32)
-    return f_lp, f_hp, ring, lat, nbr
+    return f_lp, f_hp, ring, lat
 
 
 def _tick_body(k, carry, occ_k, bscale_k, row_budget, c: TickConsts, *,
@@ -191,8 +206,8 @@ def _tick_body(k, carry, occ_k, bscale_k, row_budget, c: TickConsts, *,
     """One tick on a ``[C, R]`` member block — shared verbatim between the
     Pallas kernel body and the scan reference, so the kernel test isolates
     the pallas shell (blocking, loads/stores) rather than re-proving the
-    state machine."""
-    f_lp, f_hp, ring, lat, nbr = carry
+    state machine. Returns ``(carry', row_w, fire)``."""
+    f_lp, f_hp, ring, lat = carry
     ring, f_lp, f_hp = apply_ring_tick(ring, f_lp, f_hp, k,
                                        ring_depth=ring_depth)
     rw = row_power_w(c, occ_k, f_lp, f_hp)
@@ -204,37 +219,63 @@ def _tick_body(k, carry, occ_k, bscale_k, row_budget, c: TickConsts, *,
     ring = push_ring_commands(ring, fire, lp_cmd, hp_cmd, c.brake_freq, k,
                               oob_ticks=oob_ticks, brake_ticks=brake_ticks,
                               ring_depth=ring_depth)
-    nbr = nbr + fire.astype(jnp.int32)
-    return (f_lp, f_hp, ring, lat, nbr), rw, fire
+    return (f_lp, f_hp, ring, lat), rw, fire
 
 
 def _tick_kernel(occ_ref, bscale_ref, rb_ref,
-                 roww_ref, fire_ref, flp_ref, fhp_ref, nbr_ref, *,
-                 T, R, C, oob_ticks, brake_ticks, ring_depth, esc,
+                 roww_ref, fire_ref, flp_ref, fhp_ref, nbr_ref,
+                 f_s, ring_s, lat_s, nbr_s, *,
+                 T, TB, oob_ticks, brake_ticks, ring_depth, esc,
                  c: TickConsts):
-    """Pallas kernel body: one member block, full T-tick loop. State lives
-    in the ``fori_loop`` carry (the compiler keeps it in VMEM/registers);
-    per-tick planes stream out through the block refs."""
-    dtype = occ_ref.dtype
+    """Pallas kernel body: one ``[TB, C, R]`` (ticks x members x rows) block
+    of one member block. The grid walks member blocks (parallel) and, within
+    each, time blocks in order; the frequency/ring/latch state and the brake
+    count persist across time blocks in VMEM scratch. Ticks are the leading
+    axis so every per-tick load and store is one ``[C, R]`` tile."""
+    tb = pl.program_id(1)
 
-    def body(k, carry):
-        occ_k = pl.load(occ_ref, (slice(None), pl.dslice(k, 1),
-                                  slice(None)))[:, 0, :]
-        bscale_k = pl.load(bscale_ref, (pl.dslice(k, 1), slice(None)))[0]
-        carry, rw, fire = _tick_body(
-            k, carry, occ_k, bscale_k, rb_ref[...], c,
+    @pl.when(tb == 0)
+    def _():
+        f_lp, f_hp, ring, lat = _tick_init(f_s.shape[1], f_s.shape[2],
+                                           ring_depth, f_s.dtype)
+        f_s[...] = jnp.stack([f_lp, f_hp])
+        ring_s[...] = ring
+        lat_s[...] = jnp.stack(_latches_i32(lat))
+        nbr_s[...] = jnp.zeros(nbr_s.shape, jnp.int32)
+
+    rb = rb_ref[...]
+
+    # Mosaic cannot carry boolean vectors through a loop: the latches ride
+    # the fori_loop carry as int32 0/1 and are unpacked per tick
+    def body(j, state):
+        f_lp, f_hp, ring, lat, nbr = state
+        k = tb * TB + j
+        lat = PolcaLatches(*(a != 0 for a in lat[:4]), t2s=lat[4])
+        (f_lp, f_hp, ring, lat), rw, fire = _tick_body(
+            k, (f_lp, f_hp, ring, lat), occ_ref[j], bscale_ref[j], rb, c,
             oob_ticks=oob_ticks, brake_ticks=brake_ticks,
             ring_depth=ring_depth, esc=esc)
-        f_lp, f_hp = carry[0], carry[1]
-        idx = (slice(None), pl.dslice(k, 1), slice(None))
-        pl.store(roww_ref, idx, rw[:, None, :])
-        pl.store(fire_ref, idx, fire[:, None, :])
-        pl.store(flp_ref, idx, f_lp[:, None, :])
-        pl.store(fhp_ref, idx, f_hp[:, None, :])
-        return carry
+        roww_ref[j] = rw
+        fire_ref[j] = fire.astype(jnp.int32)
+        flp_ref[j] = f_lp
+        fhp_ref[j] = f_hp
+        # ticks past T only pad the last time block: never count them
+        nbr = nbr + jnp.where(fire & (k < T), 1, 0).astype(jnp.int32)
+        return f_lp, f_hp, ring, _latches_i32(lat), nbr
 
-    final = lax.fori_loop(0, T, body, _tick_init(C, R, ring_depth, dtype))
-    nbr_ref[...] = final[4]
+    lat0 = lat_s[...]
+    f_lp, f_hp, ring, lat, nbr = lax.fori_loop(
+        0, TB, body, (f_s[0], f_s[1], ring_s[...],
+                      tuple(lat0[i] for i in range(5)), nbr_s[...]))
+    f_s[...] = jnp.stack([f_lp, f_hp])
+    ring_s[...] = ring
+    lat_s[...] = jnp.stack(lat)
+    nbr_s[...] = nbr
+    nbr_ref[...] = nbr
+
+
+def _latches_i32(lat: PolcaLatches):
+    return tuple(a.astype(jnp.int32) for a in lat)
 
 
 def _auto_interpret(interpret):
@@ -246,7 +287,7 @@ def _auto_interpret(interpret):
 def polca_tick_loop(occ, bscale, row_budget, consts: TickConsts, *,
                     oob_ticks: int, brake_ticks: int, ring_depth: int,
                     esc: int, block_members: int = DEFAULT_BLOCK_MEMBERS,
-                    interpret=None):
+                    block_ticks: int = DEFAULT_BLOCK_TICKS, interpret=None):
     """The non-predictive POLCA tick loop as one ``pallas_call``.
 
     ``occ`` is the *effective* per-tick occupancy ``[N, T, R]`` (60 s-grid
@@ -254,48 +295,58 @@ def polca_tick_loop(occ, bscale, row_budget, consts: TickConsts, *,
     kernel owns the power fold + latch/ring update that dominates the scan
     body). ``bscale`` is the ``[T, R]`` fault budget scale, ``row_budget``
     the ``[R]`` static budgets. Members are padded to a multiple of
-    ``block_members``; the grid walks member blocks and each program
-    instance runs the full T-tick loop on its block.
+    ``block_members`` and ticks to a multiple of ``block_ticks``; the grid
+    walks member blocks x time blocks, and each member block carries its
+    state through its time blocks in order.
 
     Returns ``dict(row_w=[N, T, R], fire=[N, T, R] bool,
     f_lp=[N, T, R], f_hp=[N, T, R], n_brakes=[N, R] int32)`` — the
     frequency planes let the SLO fluid proxy run as a cheap post-pass.
     """
+    from jax.experimental.pallas import tpu as pltpu
+
     N, T, R = occ.shape
     C = max(1, min(int(block_members), N))
+    TB = max(1, min(int(block_ticks), T))
     n_pad = (-N) % C
-    if n_pad:
-        occ = jnp.concatenate([occ, occ[:n_pad]], axis=0)
-    B = (N + n_pad) // C
+    t_pad = (-T) % TB
+    occ = jnp.pad(jnp.moveaxis(occ, 1, 0), ((0, t_pad), (0, n_pad), (0, 0)),
+                  mode="edge")  # [Tp, Np, R]
+    bscale = jnp.pad(bscale, ((0, t_pad), (0, 0)), mode="edge")[:, None, :]
+    B, NT = (N + n_pad) // C, (T + t_pad) // TB
+    D = int(ring_depth)
     dtype = occ.dtype
     kernel = functools.partial(
-        _tick_kernel, T=T, R=R, C=C, oob_ticks=int(oob_ticks),
-        brake_ticks=int(brake_ticks), ring_depth=int(ring_depth),
-        esc=int(esc), c=consts)
-    plane = jax.ShapeDtypeStruct((B * C, T, R), dtype)
+        _tick_kernel, T=T, TB=TB, oob_ticks=int(oob_ticks),
+        brake_ticks=int(brake_ticks), ring_depth=D, esc=int(esc), c=consts)
+    plane = pl.BlockSpec((TB, C, R), lambda b, t: (t, b, 0))
     out = pl.pallas_call(
         kernel,
-        grid=(B,),
+        grid=(B, NT),
         in_specs=[
-            pl.BlockSpec((C, T, R), lambda b: (b, 0, 0)),
-            pl.BlockSpec((T, R), lambda b: (0, 0)),
-            pl.BlockSpec((R,), lambda b: (0,)),
+            plane,
+            pl.BlockSpec((TB, 1, R), lambda b, t: (t, 0, 0)),
+            pl.BlockSpec((1, R), lambda b, t: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((C, T, R), lambda b: (b, 0, 0)),
-            pl.BlockSpec((C, T, R), lambda b: (b, 0, 0)),
-            pl.BlockSpec((C, T, R), lambda b: (b, 0, 0)),
-            pl.BlockSpec((C, T, R), lambda b: (b, 0, 0)),
-            pl.BlockSpec((C, R), lambda b: (b, 0)),
-        ],
+        out_specs=[plane, plane, plane, plane,
+                   pl.BlockSpec((C, R), lambda b, t: (b, 0))],
         out_shape=[
-            plane,
-            jax.ShapeDtypeStruct((B * C, T, R), jnp.bool_),
-            plane,
-            plane,
+            jax.ShapeDtypeStruct(occ.shape, dtype),
+            jax.ShapeDtypeStruct(occ.shape, jnp.int32),
+            jax.ShapeDtypeStruct(occ.shape, dtype),
+            jax.ShapeDtypeStruct(occ.shape, dtype),
             jax.ShapeDtypeStruct((B * C, R), jnp.int32),
         ],
+        scratch_shapes=[
+            pltpu.VMEM((2, C, R), dtype),
+            pltpu.VMEM((D, 2, C, R), dtype),
+            pltpu.VMEM((5, C, R), jnp.int32),
+            pltpu.VMEM((C, R), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=_auto_interpret(interpret),
-    )(occ, bscale, row_budget)
-    row_w, fire, f_lp, f_hp, nbr = (a[:N] for a in out)
-    return dict(row_w=row_w, fire=fire, f_lp=f_lp, f_hp=f_hp, n_brakes=nbr)
+    )(occ, bscale, row_budget[None, :])
+    row_w, fire, f_lp, f_hp = (jnp.moveaxis(a[:T, :N], 0, 1) for a in out[:4])
+    return dict(row_w=row_w, fire=fire != 0, f_lp=f_lp, f_hp=f_hp,
+                n_brakes=out[4][:N])

@@ -1,9 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # placeholder devices; never claim a chip
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay first (before any jax import): jax locks the
+The lines above MUST stay first (before any jax import): jax locks the
 device count at first init, and the production meshes need 512 placeholder
 host devices. Smoke tests and benchmarks never import this module.
 
@@ -28,7 +29,7 @@ import jax
 
 from repro.configs import assigned_archs, get_config
 from repro.launch.inputs import input_specs, make_rules, split_seq
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import abstract_state, build_serve_step
 from repro.models.config import SHAPES_BY_NAME, shape_applicable
 from repro.obs.log import get_logger
@@ -42,7 +43,7 @@ def _lower_compile(cfg, shape, mesh, rules):
     step, opt = build_serve_step(cfg, shape, mesh, rules)
     specs = input_specs(cfg, shape, mesh, rules)
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             state = abstract_state(cfg, mesh, rules, opt)
             lowered = jax.jit(step).lower(state, specs)

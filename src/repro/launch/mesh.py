@@ -7,51 +7,15 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def set_mesh(mesh):
-    """Version-compatible ``jax.set_mesh`` for the ``with set_mesh(mesh):``
-    form ONLY.
-
-    ``jax.set_mesh`` only exists on recent jax releases. Fall back to
-    ``jax.sharding.use_mesh`` where available, and finally to the ``Mesh``
-    object itself (a context manager on every jax version we support).
-    Bare (non-``with``) calls are NOT emulated on old jax: the fallbacks
-    return an unentered context manager instead of mutating global state.
-    """
-    native = getattr(jax, "_repro_native_set_mesh", None) or getattr(jax, "set_mesh", None)
-    if native is not None and native is not set_mesh:
-        return native(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
-
-
-if hasattr(jax, "set_mesh"):
-    jax._repro_native_set_mesh = jax.set_mesh
-else:
-    # Older jax: install the shim so existing `with jax.set_mesh(...)` call
-    # sites keep working once this module is imported (with-form only; see
-    # the docstring above).
-    jax.set_mesh = set_mesh
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-compatible ``jax.shard_map``.
-
-    Recent jax exposes ``jax.shard_map`` with the ``check_vma`` kwarg; older
-    releases only have ``jax.experimental.shard_map.shard_map`` whose
-    equivalent knob is ``check_rep``. Callers that disable varying-manual
-    axis checking (the batched engine's replicated-consts layout trips it)
-    work on both."""
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        return native(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+def _make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the model and the batched
+    engine place values with ``with_sharding_constraint`` / ``shard_map``,
+    which under ``Explicit`` axes (the JAX 0.9 default) would assert the
+    spec instead of steering the partitioner."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_mesh(data: int = 0):
@@ -62,20 +26,20 @@ def data_mesh(data: int = 0):
     ``tests/conftest.py``) this exercises the real sharded path on CPU CI."""
     if data <= 0:
         data = len(jax.devices())
-    return jax.make_mesh((data,), ("data",))
+    return _make_mesh((data,), ("data",))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1, pod: int = 0):
     """Small mesh over however many devices are actually present (tests/smoke)."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _make_mesh((pod, data, model), ("pod", "data", "model"))
+    return _make_mesh((data, model), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict:

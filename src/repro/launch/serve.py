@@ -22,8 +22,9 @@ import numpy as np
 from repro.configs import get_config, smoke_config
 from repro.core.power_model import A100, ServerPower
 from repro.core.workload import request_timing
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.inputs import make_rules
-from repro.launch.mesh import make_local_mesh, set_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import build_decode_step, build_prefill_step
 from repro.models import model as model_mod
 from repro.models.config import ShapeConfig
@@ -38,7 +39,7 @@ class ServeEngine:
         self.cfg, self.mesh = cfg, mesh
         shape = ShapeConfig("serve", max_len, batch, "prefill")
         self.rules = make_rules(cfg, shape, mesh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             self.params = init_params(model_mod.model_specs(cfg, mesh.shape["model"]),
                                       jax.random.key(0))
         self.prefill = jax.jit(build_prefill_step(cfg, shape, mesh, self.rules))
@@ -50,7 +51,7 @@ class ServeEngine:
         if extra_inputs:
             batch.update(extra_inputs)
         outs = []
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             logits, cache = self.prefill(self.params, batch)
             pos = tokens.shape[1]
             tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
@@ -72,6 +73,7 @@ def main():
     ap.add_argument("--model-par", type=int, default=1)
     ap.add_argument("--report-power", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_local_mesh(max(1, len(jax.devices()) // args.model_par), args.model_par)

@@ -22,7 +22,7 @@ import numpy as np
 from repro.configs import get_config, smoke_config
 from repro.data.pipeline import DataConfig, SyntheticTokenPipeline, device_put_batch
 from repro.launch.inputs import make_rules
-from repro.launch.mesh import make_local_mesh, set_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import build_train_step
 from repro.models import model as model_mod
 from repro.models.config import ShapeConfig
@@ -57,7 +57,7 @@ def main():
 
     pspecs = model_mod.model_specs(cfg, mesh.shape["model"])
     opt = make_optimizer(cfg.optimizer)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(pspecs, jax.random.key(0))
         opt_state = init_params(opt.init_specs(pspecs), jax.random.key(1))
     state = {"params": params, "opt": opt_state}
@@ -68,7 +68,7 @@ def main():
     step_fn = jax.jit(build_train_step(cfg, mesh, rules, opt))
 
     def wrapped_step(state, batch):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             new_state, metrics = step_fn(state, batch)
             metrics = {k: float(v) for k, v in metrics.items()}
         return new_state, metrics

@@ -27,12 +27,16 @@ def attn_specs(cfg: ModelConfig, cross: bool = False) -> dict:
     D, KV, hd = cfg.d_model, cfg.num_kv_heads, cfg.head_dim
     H = cfg.padded_heads  # zero-padded wo rows: exact outputs, clean sharding
     wd = cfg.weight_dtype
+    # fan-in is the contracted size (D in, H*hd out), not shape[-2]: a
+    # per-head fan-in inflates q.k by ~sqrt(D/H) * sqrt(D/KV) and turns
+    # random-weight attention into a hard argmax that amplifies rounding
     p = {
-        "wq": ParamSpec((D, H, hd), ("embed", "heads", "head_dim"), dtype=wd),
-        "wk": ParamSpec((D, KV, hd), ("embed", "kv_heads", "head_dim"), dtype=wd),
-        "wv": ParamSpec((D, KV, hd), ("embed", "kv_heads", "head_dim"), dtype=wd),
+        "wq": ParamSpec((D, H, hd), ("embed", "heads", "head_dim"), dtype=wd, fan_in=D),
+        "wk": ParamSpec((D, KV, hd), ("embed", "kv_heads", "head_dim"), dtype=wd, fan_in=D),
+        "wv": ParamSpec((D, KV, hd), ("embed", "kv_heads", "head_dim"), dtype=wd, fan_in=D),
         "wo": ParamSpec((H, hd, D), ("heads", "head_dim", "embed"),
-                        init="zeros" if H != cfg.num_heads else "normal", dtype=wd),
+                        init="zeros" if H != cfg.num_heads else "normal", dtype=wd,
+                        fan_in=cfg.num_heads * hd),
     }
     if cfg.qk_norm and not cross:
         p["q_norm"] = ParamSpec((hd,), ("head_dim",), init="ones", dtype=wd)

@@ -10,7 +10,7 @@ O(num_layers).
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -110,7 +110,7 @@ def block_specs(cfg: ModelConfig, idx: int, kind: str, moe_shards: int, *, cross
 
 def _stack_specs(tree, n: int):
     return tree_map_specs(
-        lambda s: ParamSpec((n,) + s.shape, ("layers",) + s.logical, s.init, s.scale, s.dtype),
+        lambda s: replace(s, shape=(n,) + s.shape, logical=("layers",) + s.logical),
         tree,
     )
 

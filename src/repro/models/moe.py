@@ -31,17 +31,6 @@ from jax.sharding import PartitionSpec as P
 from repro.models.config import ModelConfig
 from repro.models.param import ParamSpec
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    # jax < 0.6: shard_map lives in jax.experimental and spells the
-    # replication check `check_rep` instead of `check_vma`.
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _experimental_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs, check_rep=check_vma)
-
 
 def moe_layout(cfg: ModelConfig, n_shards: int) -> Tuple[int, int, int, int]:
     """(e_shards, f_shards, n_local_experts, slots) for an EP domain of
@@ -165,7 +154,7 @@ def moe_apply(
         out = jax.lax.psum(out, model_axis)
         return out.reshape(B_l, S, D)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(x_spec, r_spec, w_spec, w_spec, wd_spec),
@@ -259,7 +248,7 @@ def moe_apply_token_routed(
             out = jax.lax.dynamic_slice_in_dim(out, b_idx * B_l, B_l, axis=0)
         return out
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, wd_spec),

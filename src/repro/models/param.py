@@ -23,6 +23,7 @@ class ParamSpec:
     init: str = "normal"  # normal | zeros | ones | ssm_a | ssm_dt
     scale: float = 1.0  # stddev multiplier for normal init
     dtype: Any = jnp.float32
+    fan_in: int = 0  # contracted input size for normal init; 0 = shape[-2]
 
     def __post_init__(self):
         assert len(self.shape) == len(self.logical), (self.shape, self.logical)
@@ -50,7 +51,7 @@ def init_param(spec: ParamSpec, key) -> jax.Array:
         u = jax.random.uniform(key, spec.shape, jnp.float32, 1e-3, 1e-1)
         return jnp.log(jnp.expm1(u)).astype(spec.dtype)
     # truncated-normal fan-in init
-    fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
+    fan_in = spec.fan_in or (spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1])
     std = spec.scale / np.sqrt(max(1, fan_in))
     return (jax.random.truncated_normal(key, -2.0, 2.0, spec.shape, jnp.float32) * std).astype(
         spec.dtype

@@ -824,10 +824,9 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
         # scan on its member shard — no cross-device collectives in the hot
         # loop, so throughput scales with device count.
         from jax.sharding import PartitionSpec
-        from repro.launch.mesh import shard_map_compat
         member = PartitionSpec(None, "data")
         rep = PartitionSpec()
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             run, mesh=mesh,
             in_specs=(member, rep, rep, rep, rep, rep, rep, rep),
             out_specs=member, check_vma=False)
@@ -848,23 +847,12 @@ def _geometry_key(model: TickModel) -> tuple:
             float(model.dt))
 
 
-def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
-                    keep_fire: bool = True,
-                    member_chunk: Optional[int] = None,
-                    mesh=None) -> List[BatchedRun]:
-    """Run one geometry bucket of TickModels as a single device program.
-
-    Per-scenario constants stack on a leading ``[M]`` axis and the runner
-    vmaps the scenario axis over the member program — so an M-scenario grid
-    (or an M-probe planner sweep re-using one compiled program) costs one
-    dispatch, not M. ``member_chunk`` bounds device memory by scanning
-    member blocks; ``mesh`` shards the member axis over its "data" axis.
-    Members are padded (cyclically) to the chunk x device multiple and
-    sliced back — padding members are independent lanes, so results are
-    invariant to both knobs (tier-1 asserted)."""
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
+def _plan_bucket(models: Sequence[TickModel], *, keep_series: bool,
+                 keep_fire: bool, member_chunk: Optional[int], mesh
+                 ) -> Tuple[_JaxCfg, object, np.ndarray]:
+    """The static program key, effective mesh and padded member index of one
+    geometry bucket. Members are padded (cyclically) to the chunk x device
+    multiple; padding members are independent lanes, sliced off after."""
     m0 = models[0]
     key0 = _geometry_key(m0)
     for m in models[1:]:
@@ -894,34 +882,60 @@ def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
             member_chunk = math.ceil(N / (max(1, n_dev) * n_blocks))
     chunk = max(0, int(member_chunk or 0))
     mult = max(1, n_dev) * max(1, chunk)
-    n_pad = (-N) % mult
-    idx = np.resize(np.arange(N), N + n_pad)
+    idx = np.resize(np.arange(N), N + (-N) % mult)
     cfg = _JaxCfg(T=m0.n_ticks, R=m0.n_rows, D=m0.ring_depth,
                   W=max(1, m0.window), S=m0.n_slots, stride=m0.stride,
                   oob_ticks=m0.oob_ticks, brake_ticks=m0.brake_ticks,
                   esc=m0.escalation_ticks, predictive=m0.predictive,
                   keep_series=keep_series, keep_fire=keep_fire, chunk=chunk)
-    runner = _jax_runner(cfg, mesh)
-    with enable_x64():
-        def _f(vals):
-            return jnp.asarray(np.asarray(vals, dtype=np.float64))
+    return cfg, mesh, idx
 
-        occ60_g = jnp.asarray(np.stack([m.occ60[idx] for m in models]))
-        consts_g = _Consts(
-            **{name: _f([_model_const(m, name) for m in models])
-               for name in _CONST_SCALARS},
-            row_budget=_f(np.stack([m.row_budget_w for m in models])))
-        # shared across the bucket by construction (geometry-keyed): pass
-        # unbatched so the runner's scenario vmap broadcasts them
-        i_idx, i_w = _interp_weights(m0)
-        t_g = _f(m0.tick_times())
-        ii_g = jnp.asarray(i_idx, dtype=jnp.int32)
-        iw_g = _f(i_w)
-        alive_g = _f(np.stack([m.alive for m in models]))
-        bscale_g = _f(np.stack([m.budget_scale for m in models]))
-        ks = jnp.arange(cfg.T, dtype=jnp.int32)
-        out = runner(occ60_g, consts_g, t_g, ii_g, iw_g, alive_g, bscale_g,
-                     ks)
+
+def _bucket_operands(models: Sequence[TickModel], idx: np.ndarray) -> tuple:
+    """The runner's operands for one bucket, as host arrays (float64 where
+    the program computes in float64). Per-scenario constants stack on a
+    leading ``[M]`` axis; the tick grid (``t``/``ii``/``iw``) is shared
+    across the bucket by construction (geometry-keyed) and passes unbatched
+    so the runner's scenario vmap broadcasts it."""
+    m0 = models[0]
+
+    def f64(vals):
+        return np.asarray(vals, dtype=np.float64)
+
+    i_idx, i_w = _interp_weights(m0)
+    consts = _Consts(
+        **{name: f64([_model_const(m, name) for m in models])
+           for name in _CONST_SCALARS},
+        row_budget=f64(np.stack([m.row_budget_w for m in models])))
+    return (np.stack([m.occ60[idx] for m in models]), consts,
+            f64(m0.tick_times()), np.asarray(i_idx, dtype=np.int32), f64(i_w),
+            f64(np.stack([m.alive for m in models])),
+            f64(np.stack([m.budget_scale for m in models])),
+            np.arange(m0.n_ticks, dtype=np.int32))
+
+
+def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
+                    keep_fire: bool = True,
+                    member_chunk: Optional[int] = None,
+                    mesh=None) -> List[BatchedRun]:
+    """Run one geometry bucket of TickModels as a single device program.
+
+    Per-scenario constants stack on a leading ``[M]`` axis and the runner
+    vmaps the scenario axis over the member program — so an M-scenario grid
+    (or an M-probe planner sweep re-using one compiled program) costs one
+    dispatch, not M. ``member_chunk`` bounds device memory by scanning
+    member blocks; ``mesh`` shards the member axis over its "data" axis.
+    Results are invariant to both knobs (tier-1 asserted)."""
+    import jax
+
+    cfg, mesh, idx = _plan_bucket(models, keep_series=keep_series,
+                                  keep_fire=keep_fire,
+                                  member_chunk=member_chunk, mesh=mesh)
+    N = models[0].n_members
+    operands = _bucket_operands(models, idx)
+    runner = _jax_runner(cfg, mesh)
+    with jax.enable_x64(True):
+        out = runner(*jax.tree.map(jax.numpy.asarray, operands))
         out = {k: np.asarray(v) for k, v in out.items()}
     runs: List[BatchedRun] = []
     for i, m in enumerate(models):
@@ -972,8 +986,13 @@ def _run_pallas(model: TickModel, keep_series: bool) -> BatchedRun:
             "engine='pallas' runs the non-predictive PolcaPolicy tick loop; "
             f"{model.base_name!r} lowered a predictive policy (use "
             "engine='jax', which carries the slope window in scan state)")
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "engine='pallas' runs the tick kernel in float64 (the oracle "
+            "contract), and Mosaic has no float64 on TPU; use engine='jax'")
 
     from repro.kernels import ops as kops
     from repro.kernels.tick import TickConsts
@@ -993,7 +1012,7 @@ def _run_pallas(model: TickModel, keep_series: bool) -> BatchedRun:
         k_lp_w=model.k_lp_w, k_hp_w=model.k_hp_w, lp_share=model.lp_share,
         gamma=model.gamma, n_servers=model.n_servers,
         power_scale=model.power_scale)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = kops.polca_tick(
             jnp.asarray(occ_ntr), jnp.asarray(model.budget_scale),
             jnp.asarray(model.row_budget_w), consts=consts,

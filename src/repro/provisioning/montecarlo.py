@@ -54,6 +54,7 @@ from __future__ import annotations
 import math
 import multiprocessing
 import os
+import sys
 import warnings
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -455,11 +456,23 @@ def _run_shard_pool(scenarios: List[Scenario], stride: float,
     return out
 
 
+def _holds_accelerator() -> bool:
+    """True once this process has initialised a non-CPU JAX backend. A
+    forked child would inherit the parent's hold on the chip (and JAX's
+    threads), so the pool then runs inline. Asks without initialising."""
+    if "jax" not in sys.modules:
+        return False
+    import jax
+    from jax._src import xla_bridge
+    return (xla_bridge.backends_are_initialized()
+            and jax.default_backend() != "cpu")
+
+
 def _map_shards(shards: List[Tuple[List[Scenario], float, int]],
                 n_workers: int
                 ) -> List[Tuple[List[Tuple[SimResult, LatencyStats]],
                                 Optional[MetricsSnapshot]]]:
-    if n_workers <= 1 or len(shards) <= 1:
+    if n_workers <= 1 or len(shards) <= 1 or _holds_accelerator():
         return [_run_shard(sh) for sh in shards]
     try:
         ctx = multiprocessing.get_context("fork")

@@ -11,7 +11,7 @@ from repro.checkpoint import checkpointer
 from repro.configs import smoke_config
 from repro.data.pipeline import DataConfig, SyntheticTokenPipeline
 from repro.launch.inputs import make_rules
-from repro.launch.mesh import make_local_mesh, set_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import build_train_step
 from repro.models import model as model_mod
 from repro.models.config import ShapeConfig
@@ -66,7 +66,7 @@ def test_supervisor_crash_restart_replays_exactly(tmp_path, mesh1):
     rules = make_rules(cfg, shape, mesh1)
     opt = make_optimizer(cfg.optimizer)
     pspecs = model_mod.model_specs(cfg, 1)
-    with set_mesh(mesh1):
+    with jax.set_mesh(mesh1):
         params = init_params(pspecs, jax.random.key(0))
         opt_state = init_params(opt.init_specs(pspecs), jax.random.key(1))
     state0 = {"params": params, "opt": opt_state}
@@ -74,7 +74,7 @@ def test_supervisor_crash_restart_replays_exactly(tmp_path, mesh1):
     base_step = jax.jit(build_train_step(cfg, mesh1, rules, opt))
 
     def clean_step(state, batch):
-        with set_mesh(mesh1):
+        with jax.set_mesh(mesh1):
             s, m = base_step(state, {k: jnp.asarray(v) for k, v in batch.items()})
         return s, m
 

@@ -75,12 +75,12 @@ TICK_CONSTS = dict(t1=0.90, t2=0.97, t1_buf=0.02, t2_buf=0.02,
                    power_scale=1.10)
 
 TICK_CASES = [
-    # (N, T, R, block_members, oob, brake, esc, power_scale)
-    (8, 96, 2, 8, 20, 3, 25, 1.10),
-    (5, 96, 2, 8, 20, 3, 25, 1.10),   # N not a block multiple (padding)
-    (13, 64, 3, 4, 20, 3, 25, 1.18),  # hot: brakes fire
-    (3, 48, 1, 8, 5, 2, 4, 1.05),     # short ring, fast escalation
-    (16, 32, 2, 16, 20, 3, 25, 0.95), # cool: mostly uncapped
+    # (N, T, R, block_members, oob, brake, esc, power_scale, block_ticks)
+    (8, 96, 2, 8, 20, 3, 25, 1.10, 32),   # three time blocks
+    (5, 96, 2, 8, 20, 3, 25, 1.10, 40),   # N, T not block multiples (padding)
+    (13, 64, 3, 4, 20, 3, 25, 1.18, 64),  # hot: brakes fire
+    (3, 48, 1, 8, 5, 2, 4, 1.05, 7),      # short ring, fast escalation
+    (16, 32, 2, 16, 20, 3, 25, 0.95, 256),  # cool: mostly uncapped
 ]
 
 
@@ -91,10 +91,10 @@ def test_polca_tick_vs_ref(case):
     to 1e-6 relative, brake/frequency planes bit-identical (float64)."""
     from repro.kernels.tick import TickConsts
 
-    N, T, R, bm, oob, brake, esc, ps = case
+    N, T, R, bm, oob, brake, esc, ps, tb = case
     ring_depth = max(oob, brake) + 1
     consts = TickConsts(**{**TICK_CONSTS, "power_scale": ps})
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(N * 1000 + T)
         occ = jnp.asarray(rng.uniform(0.3, 1.0, (N, T, R)))
         bscale = jnp.asarray(rng.uniform(0.9, 1.0, (T, R)))
@@ -104,7 +104,8 @@ def test_polca_tick_vs_ref(case):
         got = ops.polca_tick(occ, bscale, row_budget, consts=consts,
                              oob_ticks=oob, brake_ticks=brake,
                              ring_depth=ring_depth, esc=esc,
-                             block_members=bm, interpret=True)
+                             block_members=bm, block_ticks=tb,
+                             interpret=True)
         want = ref.polca_tick_reference(occ, bscale, row_budget, consts,
                                         oob_ticks=oob, brake_ticks=brake,
                                         ring_depth=ring_depth, esc=esc)
@@ -126,7 +127,7 @@ def test_polca_tick_brakes_actually_fire():
     from repro.kernels.tick import TickConsts
 
     consts = TickConsts(**{**TICK_CONSTS, "power_scale": 1.30})
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         occ = jnp.ones((4, 64, 2)) * 0.98
         out = ops.polca_tick(occ, jnp.ones((64, 2)),
                              jnp.full(2, consts.n_servers * 250.0),

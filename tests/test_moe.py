@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 from repro.configs import smoke_config
-from repro.launch.mesh import set_mesh
 from repro.models import moe
 from repro.models.param import init_params
 from repro.launch.mesh import make_local_mesh
@@ -65,7 +64,7 @@ def test_moe_matches_dense_reference(n_model, E):
     mesh = make_local_mesh(1, n_model)
     p = init_params(moe.moe_specs(cfg, n_model), KEY)
     x = jax.random.normal(jax.random.fold_in(KEY, E), (2, 8, cfg.d_model)) * 0.5
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(lambda pp, xx: moe.moe_apply(
             cfg, pp, xx, mesh=mesh, batch_spec=None, gather_axes=()))(p, x)
     want = dense_moe_reference(cfg, p, x)
@@ -81,7 +80,7 @@ def test_moe_capacity_drops_bounded():
     mesh = make_local_mesh(1, 1)
     p = init_params(moe.moe_specs(cfg, 1), KEY)
     x = jax.random.normal(KEY, (4, 16, cfg.d_model))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = moe.moe_apply(cfg, p, x, mesh=mesh, batch_spec=None, gather_axes=())
     assert np.isfinite(np.asarray(out)).all()
 
@@ -122,7 +121,7 @@ def test_token_routed_matches_dense_reference(n_dev_needed, batch_sharded):
     p = init_params(moe.moe_specs(cfg, ep), KEY)
     x = jax.random.normal(jax.random.fold_in(KEY, 5), (2, 8, cfg.d_model)) * 0.5
     bspec = ("data",) if batch_sharded else None
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(lambda pp, xx: moe.moe_apply_token_routed(
             cfg, pp, xx, mesh=mesh, batch_spec=bspec))(p, x)
     want = dense_moe_reference(cfg, p, x)

@@ -10,7 +10,6 @@ import pytest
 from conftest import make_batch
 from repro.configs import ALL, ASSIGNED, smoke_config
 from repro.launch.inputs import make_rules, split_seq
-from repro.launch.mesh import set_mesh
 from repro.launch.steps import build_decode_step, build_prefill_step, build_train_step
 from repro.models import model as model_mod
 from repro.models.config import ShapeConfig
@@ -38,7 +37,7 @@ def test_train_step_all_archs(name, mesh1):
     state = {"params": params, "opt": opt_state}
     batch = make_batch(cfg, B, S)
     step = jax.jit(build_train_step(cfg, mesh1, rules, opt))
-    with set_mesh(mesh1):
+    with jax.set_mesh(mesh1):
         state2, metrics = step(state, batch)
         state3, metrics3 = step(state2, batch)
     loss = float(metrics["loss"])
@@ -71,7 +70,7 @@ def test_prefill_decode_consistency(name, mesh1):
     b_part["tokens"] = batch["tokens"][:, :-1]
     img = cfg.num_image_embeds if cfg.frontend == "vision_stub" else 0
     pos = jnp.asarray(n_txt - 1 + img, jnp.int32)
-    with set_mesh(mesh1):
+    with jax.set_mesh(mesh1):
         logits_full, _ = pf(params, batch)
         _, cache = pf(params, b_part)
         logits_dec, new_cache = dc(params, batch["tokens"][:, -1:], pos, cache)
@@ -90,7 +89,7 @@ def test_output_shapes_and_no_nans(name, mesh1):
         pytest.skip("encoder-only")
     batch = make_batch(cfg, B, S)
     pf = jax.jit(build_prefill_step(cfg, shape, mesh1, rules))
-    with set_mesh(mesh1):
+    with jax.set_mesh(mesh1):
         logits, cache = pf(params, batch)
     assert logits.shape == (B, 1, cfg.vocab_size)
     assert np.isfinite(np.asarray(logits, np.float32)).all()
@@ -109,3 +108,21 @@ def test_greedy_generation_deterministic(mesh1):
     out2 = eng.generate(toks, 8)
     assert (out1 == out2).all()
     assert out1.shape == (2, 8)
+
+
+def test_attention_init_uses_contracted_fan_in():
+    """q/k/v/o projections are [D, H, hd] / [H, hd, D]; their init std must
+    follow the contracted size (D in, H*hd out) like the MLP's, or random
+    weights make attention a hard argmax that amplifies every rounding."""
+    cfg = smoke_config("llama3.2-1b")
+    p = init_params(model_mod.model_specs(cfg, 1), jax.random.key(0))
+    layer = p["decoder"]["b0"]
+    D, H, hd = cfg.d_model, cfg.num_heads, cfg.head_dim
+
+    def unit_std(w, fan_in):
+        return float(np.std(np.asarray(w))) * np.sqrt(fan_in)
+
+    ref = unit_std(layer["mlp"]["w_gate"], D)
+    for name in ("wq", "wk", "wv"):
+        assert unit_std(layer["attn"][name], D) == pytest.approx(ref, rel=0.1)
+    assert unit_std(layer["attn"]["wo"], H * hd) == pytest.approx(ref, rel=0.1)

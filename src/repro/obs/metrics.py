@@ -27,11 +27,15 @@ The cardinal rule is that observability *observes, never perturbs*:
 Timestamps: simulation-domain events carry *simulation* time in ``t`` so
 event traces are deterministic across runs and worker counts; wall-clock
 lives only in spans (which are aggregated, and excluded from determinism
-guarantees by nature).
+guarantees by nature). Where JAX is loaded already, a recorder's span is
+also a ``jax.profiler.TraceAnnotation`` named ``polca/<name>``, so a
+profiler trace places it on the device's clock; this module never imports
+JAX itself.
 """
 
 from __future__ import annotations
 
+import sys
 import time
 from bisect import bisect_left
 from contextlib import contextmanager
@@ -256,22 +260,39 @@ class NullRecorder:
 NULL_RECORDER = NullRecorder()
 
 
+def _profiler_annotation(name: str):
+    """``jax.profiler.TraceAnnotation("polca/<name>")`` where JAX is loaded
+    already, else None: a span shows in a profiler trace without this
+    module importing JAX."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation("polca/" + name)
+
+
 class _Span:
     """Wall-clock timing context for one named stage; folds into the
-    recorder's per-(name, labels) :class:`SpanStats` on exit."""
+    recorder's per-(name, labels) :class:`SpanStats` on exit. While open it
+    is also a profiler annotation (:func:`_profiler_annotation`), outside
+    the timed stretch."""
 
-    __slots__ = ("_rec", "_key", "_t0")
+    __slots__ = ("_rec", "_key", "_t0", "_ann")
 
     def __init__(self, rec: "MetricsRecorder", key: MetricKey):
         self._rec = rec
         self._key = key
 
     def __enter__(self):
+        self._ann = _profiler_annotation(self._key[0])
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         stats = self._rec.spans.get(self._key)
         if stats is None:
             stats = self._rec.spans[self._key] = SpanStats()

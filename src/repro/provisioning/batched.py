@@ -802,7 +802,8 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
         return jax.tree_util.tree_map(
             lambda a: a.reshape((N,) + a.shape[2:]), outs)
 
-    def run(occ60_g, consts_g, t_g, ii_g, iw_g, alive_g, bscale_g, ks):
+    # the jit takes this function's name: the program is ``jit_tick_scan``
+    def tick_scan(occ60_g, consts_g, t_g, ii_g, iw_g, alive_g, bscale_g, ks):
         _TRACE_EVENTS.append(cfg)
 
         def scenario(occ60_all, consts, t, ii, iw, alive, bscale):
@@ -817,7 +818,7 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
         return jax.vmap(scenario, in_axes=(0, 0, None, None, None, 0, 0))(
             occ60_g, consts_g, t_g, ii_g, iw_g, alive_g, bscale_g)
 
-    fn = run
+    fn = tick_scan
     if mesh is not None:
         # shard the member axis (dim 1 everywhere) over the mesh's "data"
         # axis; constants/timelines replicate. Each device runs the whole
@@ -827,7 +828,7 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
         member = PartitionSpec(None, "data")
         rep = PartitionSpec()
         fn = jax.shard_map(
-            run, mesh=mesh,
+            tick_scan, mesh=mesh,
             in_specs=(member, rep, rep, rep, rep, rep, rep, rep),
             out_specs=member, check_vma=False)
     # donating the occupancy grid lets XLA reuse its buffer for outputs on
@@ -925,39 +926,62 @@ def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
     (or an M-probe planner sweep re-using one compiled program) costs one
     dispatch, not M. ``member_chunk`` bounds device memory by scanning
     member blocks; ``mesh`` shards the member axis over its "data" axis.
-    Results are invariant to both knobs (tier-1 asserted)."""
+    Results are invariant to both knobs (tier-1 asserted).
+
+    Each step is a span of the current recorder (``batched/operands``,
+    ``/h2d``, ``/run``, ``/d2h``, ``/unpack``), and the bytes each way are
+    its counters ``batched_h2d_bytes_total`` and ``batched_d2h_bytes_total``.
+    Only a recorder that is enabled makes the copy to the device wait, so
+    that ``batched/h2d`` times the copy and not its enqueue."""
     import jax
 
-    cfg, mesh, idx = _plan_bucket(models, keep_series=keep_series,
-                                  keep_fire=keep_fire,
-                                  member_chunk=member_chunk, mesh=mesh)
+    rec = get_recorder()
+    with rec.span("batched/operands"):
+        cfg, mesh, idx = _plan_bucket(models, keep_series=keep_series,
+                                      keep_fire=keep_fire,
+                                      member_chunk=member_chunk, mesh=mesh)
+        operands = _bucket_operands(models, idx)
     N = models[0].n_members
-    operands = _bucket_operands(models, idx)
-    runner = _jax_runner(cfg, mesh)
     with jax.enable_x64(True):
-        out = runner(*jax.tree.map(jax.numpy.asarray, operands))
-        out = {k: np.asarray(v) for k, v in out.items()}
+        with rec.span("batched/h2d"):
+            args = jax.tree.map(jax.numpy.asarray, operands)
+            if rec.enabled:
+                jax.block_until_ready(args)
+        with rec.span("batched/run"):
+            out = _jax_runner(cfg, mesh)(*args)
+            jax.block_until_ready(out)
+        del args  # the operands' device buffers go before the copy back
+        with rec.span("batched/d2h"):
+            out = {k: np.asarray(v) for k, v in out.items()}
+    if rec.enabled:
+        rec.counter("batched_h2d_bytes_total", float(sum(
+            a.nbytes for a in jax.tree.leaves(operands))))
+        rec.counter("batched_d2h_bytes_total",
+                    float(sum(v.nbytes for v in out.values())))
     runs: List[BatchedRun] = []
-    for i, m in enumerate(models):
-        sub = {k: v[i][:N] for k, v in out.items()}
-        imp = sub["imp"]  # [N, S, R, 2]
-        run = BatchedRun(
-            engine="jax", model=m,
-            brake_fire=(np.asarray(sub["fire"], dtype=bool)
-                        if keep_fire else None),
-            n_brakes=np.asarray(sub["nbr"], dtype=np.int64),
-            peak_frac=np.asarray(sub["peak"], dtype=np.float64),
-            mean_frac=np.asarray(sub["mean"], dtype=np.float64),
-            impacts_hp=np.ascontiguousarray(imp[:, :, :, 0].transpose(0, 2, 1)),
-            impacts_lp=np.ascontiguousarray(imp[:, :, :, 1].transpose(0, 2, 1)),
-        )
-        if keep_series:
-            run.total_frac = np.asarray(sub["frac"], dtype=np.float64)
-            run.row_w = np.asarray(sub["row_w"], dtype=np.float64)
-            if m.node_matrix is not None:
-                run.node_w = np.einsum("ntr,mr->ntm", run.row_w,
-                                       m.node_matrix)
-        runs.append(run)
+    with rec.span("batched/unpack"):
+        for i, m in enumerate(models):
+            sub = {k: v[i][:N] for k, v in out.items()}
+            imp = sub["imp"]  # [N, S, R, 2]
+            run = BatchedRun(
+                engine="jax", model=m,
+                brake_fire=(np.asarray(sub["fire"], dtype=bool)
+                            if keep_fire else None),
+                n_brakes=np.asarray(sub["nbr"], dtype=np.int64),
+                peak_frac=np.asarray(sub["peak"], dtype=np.float64),
+                mean_frac=np.asarray(sub["mean"], dtype=np.float64),
+                impacts_hp=np.ascontiguousarray(
+                    imp[:, :, :, 0].transpose(0, 2, 1)),
+                impacts_lp=np.ascontiguousarray(
+                    imp[:, :, :, 1].transpose(0, 2, 1)),
+            )
+            if keep_series:
+                run.total_frac = np.asarray(sub["frac"], dtype=np.float64)
+                run.row_w = np.asarray(sub["row_w"], dtype=np.float64)
+                if m.node_matrix is not None:
+                    run.node_w = np.einsum("ntr,mr->ntm", run.row_w,
+                                           m.node_matrix)
+            runs.append(run)
     return runs
 
 

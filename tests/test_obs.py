@@ -200,6 +200,205 @@ def test_ensemble_bit_parity_and_worker_invariant_traces():
     assert any(name == "mc/shard" for (name, _) in s1.spans)
 
 
+# ------------------------------------- batched engine: spans and counters
+BATCHED_SPANS = ("batched/operands", "batched/h2d", "batched/run",
+                 "batched/d2h", "batched/unpack")
+
+
+def _batched_scenario():
+    """Hot enough that the planner's top probe brakes and its bottom one
+    does not: a 3-probe bisection (2 servers, then 0, then 1)."""
+    from conftest import parity_scenario
+
+    return parity_scenario(occ_peak=0.99, power_scale=1.30,
+                           duration_s=1800.0, n_provisioned=10,
+                           added_frac=0.0)
+
+
+def _plan(sc):
+    from repro.provisioning.planner import RiskConstraints, plan_capacity
+
+    return plan_capacity(sc, n_seeds=4, seed0=42, engine="jax",
+                         constraints=RiskConstraints(
+                             max_brakes=0, max_slo_violation_prob=1.0),
+                         max_added_frac=0.2)
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs every open and
+    close with the names of the annotations open around it."""
+
+    def __init__(self):
+        self.stack, self.opened = [], []
+
+    def __call__(self, name):
+        outer = self
+
+        class _Annotation:
+            def __enter__(self):
+                outer.opened.append((name, tuple(outer.stack)))
+                outer.stack.append(name)
+
+            def __exit__(self, *exc):
+                assert outer.stack.pop() == name
+                return False
+        return _Annotation()
+
+    def children(self, parent):
+        """The annotations opened directly inside ``parent``, in order."""
+        return [n for n, around in self.opened
+                if around and around[-1] == parent]
+
+
+def _batched_nbytes(model, n_dispatches):
+    """Bytes each way of ``n_dispatches`` runs of ``model`` on the jax
+    engine, from its shapes: operands in, outputs out (brake plane and
+    series kept, members unpadded)."""
+    from repro.provisioning import batched
+
+    N, R, T, S = model.n_members, model.n_rows, model.n_ticks, model.n_slots
+    T60 = model.occ60.shape[2]
+    h2d = (8 * N * R * T60 + 8 * len(batched._CONST_SCALARS) + 8 * R
+           + (8 + 4 + 8 + 4) * T + 2 * 8 * T * R)
+    d2h = (4 * N * R + 8 * N + 8 * N + 8 * N * S * R * 2 + N * T * R
+           + 8 * N * T + 8 * N * T * R)
+    return n_dispatches * h2d, n_dispatches * d2h
+
+
+def test_batched_spans_nest_once_per_dispatch(monkeypatch):
+    """Each jax-engine dispatch opens the five ``batched/*`` spans, in
+    order, directly inside its ``mc/run_batched``; on the profiler's clock
+    they are ``polca/<name>`` annotations."""
+    import jax
+
+    from repro.provisioning.batched import run_batched_ensemble
+
+    sc = _batched_scenario()
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    rec = MetricsRecorder()
+    with recording(rec):
+        run_batched_ensemble(EnsembleSpec(sc, n_seeds=3, seed0=7),
+                             engine="jax")
+        plan = _plan(sc)
+    assert len(plan.probes) == 3
+    n_dispatch = 1 + len(plan.probes)
+    assert ann.children("polca/mc/run_batched") == \
+        ["polca/" + n for n in BATCHED_SPANS] * n_dispatch
+    runs = [around for n, around in ann.opened if n == "polca/mc/run_batched"]
+    assert runs == [()] + [("polca/planner/probe",)] * len(plan.probes)
+    assert not ann.stack
+    spans = {name: s.count for (name, _), s in rec.snapshot().spans.items()
+             if name.startswith("batched/")}
+    assert spans == {n: n_dispatch for n in BATCHED_SPANS}
+
+
+def test_batched_byte_counters_match_the_shapes():
+    from repro.provisioning.batched import _auto_flags, lower_ensemble
+
+    sc = _batched_scenario()
+    spec = EnsembleSpec(sc, n_seeds=3, seed0=7)
+    model, _, _ = lower_ensemble(spec)
+    assert _auto_flags(model, None, None, None)[:2] == (True, True)
+    rec = MetricsRecorder()
+    with recording(rec):
+        from repro.provisioning.batched import run_batched_ensemble
+
+        run_batched_ensemble(spec, engine="jax")
+    snap = rec.snapshot()
+    h2d, d2h = _batched_nbytes(model, 1)
+    assert snap.counter_total("batched_h2d_bytes_total") == h2d
+    assert snap.counter_total("batched_d2h_bytes_total") == d2h
+    # a plan's probes: one dispatch each, at the probe's own fleet size
+    rec = MetricsRecorder()
+    with recording(rec):
+        plan = _plan(sc)
+    want = np.sum([_batched_nbytes(lower_ensemble(EnsembleSpec(
+        sc.with_fleet(added_frac=p.added_frac), n_seeds=4, seed0=42))[0], 1)
+        for p in plan.probes], axis=0)
+    snap = rec.snapshot()
+    assert [snap.counter_total("batched_h2d_bytes_total"),
+            snap.counter_total("batched_d2h_bytes_total")] == want.tolist()
+
+
+def test_batched_engine_bit_parity_recorder_on_vs_off():
+    """Brake sets, power and planner decisions are bit-identical with the
+    recorder on and off."""
+    from repro.provisioning.batched import lower_ensemble, run_tick_model
+
+    sc = _batched_scenario()
+    # the plan's top probe: two of its four members brake
+    model, members, _ = lower_ensemble(EnsembleSpec(
+        sc.with_fleet(added_frac=0.2), n_seeds=4, seed0=42))
+    off = run_tick_model(model, members, engine="jax")
+    with recording(MetricsRecorder()):
+        on = run_tick_model(model, members, engine="jax")
+    assert off.n_brakes.sum() > 0, "the scenario should brake"
+    for name in ("brake_fire", "n_brakes", "peak_frac", "mean_frac",
+                 "total_frac", "row_w", "impacts_hp", "impacts_lp"):
+        a, b = getattr(off, name), getattr(on, name)
+        assert a.dtype == b.dtype and np.array_equal(a, b), name
+    plan_off = _plan(sc)
+    with recording(MetricsRecorder()):
+        plan_on = _plan(sc)
+    assert plan_on.safe_added_servers == plan_off.safe_added_servers
+    assert [(p.added_servers, p.feasible, p.brake_prob,
+             p.slo_violation_prob, p.peak_frac_max) for p in plan_on.probes] \
+        == [(p.added_servers, p.feasible, p.brake_prob,
+             p.slo_violation_prob, p.peak_frac_max) for p in plan_off.probes]
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_batched_engine_waits_on_operands_only_when_recording(enabled,
+                                                              monkeypatch):
+    """Untraced, the engine waits once, for its outputs; a recorder adds
+    one wait, on the copied operands, so ``batched/h2d`` times the copy."""
+    import jax
+
+    from repro.provisioning.batched import lower_ensemble, run_tick_model
+
+    model, members, _ = lower_ensemble(EnsembleSpec(_batched_scenario(),
+                                                    n_seeds=2, seed0=3))
+    waited = []
+    orig = jax.block_until_ready
+
+    def counting(x):
+        waited.append(x)
+        return orig(x)
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    with recording(MetricsRecorder() if enabled else None):
+        run_tick_model(model, members, engine="jax")
+    assert [type(x) for x in waited] == [tuple] * enabled + [dict]
+
+
+def test_batched_runner_is_named_tick_scan():
+    """The jitted runner has a stable name in profiles: ``jit_tick_scan``."""
+    import jax
+
+    from repro.provisioning import batched
+
+    model, _, _ = batched.lower_ensemble(EnsembleSpec(_batched_scenario(),
+                                                      n_seeds=2, seed0=3))
+    cfg, mesh, idx = batched._plan_bucket(
+        [model], keep_series=False, keep_fire=False, member_chunk=None,
+        mesh=None)
+    with jax.enable_x64(True):
+        lowered = batched._jax_runner(cfg, mesh).lower(
+            *batched._bucket_operands([model], idx))
+    assert lowered.as_text().startswith("module @jit_tick_scan ")
+
+
+def test_importing_obs_leaves_jax_unloaded():
+    import subprocess
+    import sys
+
+    code = ("import sys, repro.obs, repro.obs.metrics; "
+            "sys.exit('jax' in sys.modules)")
+    p = subprocess.run([sys.executable, "-c", code], env=dict(
+        os.environ, PYTHONPATH=os.pathsep.join(sys.path)), timeout=120)
+    assert p.returncode == 0
+
+
 # ------------------------------------------------------------ histogram math
 def test_histogram_merge_is_concatenation():
     """Property: merge(hist(A), hist(B)) == hist(A ++ B), across random

@@ -63,10 +63,11 @@ CVaR gate in ``RiskConstraints``.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from dataclasses import dataclass, field
 from functools import lru_cache
-from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -155,10 +156,19 @@ class TickModel:
     node_matrix: Optional[np.ndarray] = field(default=None, repr=False)  # [n_nodes, R]
     node_names: Tuple[str, ...] = ()
     seeds: Tuple[int, ...] = ()
+    # [N] fleet size of each member where the members of several candidate
+    # fleets share one model (stack_tick_models); None = n_servers for all
+    member_servers: Optional[np.ndarray] = field(default=None, repr=False)
 
     @property
     def total_budget_w(self) -> float:
         return float(self.row_budget_w.sum())
+
+    def servers(self) -> np.ndarray:
+        """[N] fleet size of each member, as the program reads it."""
+        if self.member_servers is None:
+            return np.full(self.n_members, float(self.n_servers))
+        return self.member_servers
 
     def tick_times(self) -> np.ndarray:
         """Telemetry timestamps: tick k samples t = (k+1) * dt."""
@@ -503,6 +513,9 @@ def _run_oracle(model: TickModel, members: List[Scenario],
     total_budget = model.total_budget_w
 
     for m, member in enumerate(members):
+        # the member's own fleet size where candidates share the model
+        pm = (model if model.member_servers is None else dataclasses.replace(
+            model, n_servers=int(model.member_servers[m])))
         policies = [member.policy.build() for _ in range(R)]
         f_lp = np.ones(R)
         f_hp = np.ones(R)
@@ -521,7 +534,7 @@ def _run_oracle(model: TickModel, members: List[Scenario],
             ring[:, slot, :] = np.nan
             occ = (occ60[:, i_idx[k]] * (1.0 - i_w[k])
                    + occ60[:, i_idx[k] + 1] * i_w[k]) * model.alive[k]
-            rw = _row_power_w(model, occ, f_lp, f_hp, np)
+            rw = _row_power_w(pm, occ, f_lp, f_hp, np)
             frac = float(rw.sum()) / total_budget
             frac_peak = max(frac_peak, frac)
             frac_sum += frac
@@ -530,7 +543,7 @@ def _run_oracle(model: TickModel, members: List[Scenario],
                 row_w_out[m, k] = rw
             tick_budget = model.row_budget_w * model.budget_scale[k]
             p = rw / tick_budget
-            lp_frac = _lp_power_w(model, occ, f_lp, np) / tick_budget
+            lp_frac = _lp_power_w(pm, occ, f_lp, np) / tick_budget
             for r in range(R):
                 pol = policies[r]
                 before = pol.n_brakes
@@ -573,11 +586,11 @@ class _JaxCfg(NamedTuple):
     """Static (compile-time) shape/flag key for the jitted runner.
 
     Deliberately *only* shapes and branch flags: every scalar constant
-    (thresholds, power coefficients, ``n_servers`` — which changes per
-    ``plan_capacity`` probe) travels as a traced operand in :class:`_Consts`,
-    so one compiled program serves a whole probe bisection and every
-    scenario of a grid bucket. ``jax_trace_count()`` is the regression
-    hook asserting that."""
+    (thresholds, power coefficients) travels as a traced operand in
+    :class:`_Consts`, and the fleet size — which changes per
+    ``plan_capacity`` candidate — as a per-member operand, so one compiled
+    program serves a whole decision and every scenario of a grid bucket.
+    ``jax_trace_count()`` is the regression hook asserting that."""
 
     T: int
     R: int
@@ -598,8 +611,11 @@ class _Consts(NamedTuple):
     """Traced per-scenario constants of the tick program. Scalar leaves are
     0-d (single scenario) or ``[M]`` (grid mode — the scenario-axis vmap
     maps over the leading axis of every leaf); ``row_budget`` is ``[R]`` /
-    ``[M, R]``. Field names match :class:`repro.kernels.tick.TickConsts`
-    so the shared step math reads either."""
+    ``[M, R]``. ``n_servers`` is ``None`` in the operands: the fleet size
+    is per member (the runner's ``[M, N]`` operand) and each member's
+    program fills it in. Field names match
+    :class:`repro.kernels.tick.TickConsts` so the shared step math reads
+    either."""
 
     t1: object
     t2: object
@@ -628,9 +644,8 @@ class _Consts(NamedTuple):
 
 _CONST_SCALARS = (
     "t1", "t2", "t1_buf", "t2_buf", "lp_t1", "lp_t2", "hp_t2", "brake_freq",
-    "p0_srv_w", "k_lp_w", "k_hp_w", "lp_share", "gamma", "n_servers",
-    "power_scale", "dt", "horizon", "a_hp", "a_lp", "svc_hp", "svc_lp",
-    "total_budget")
+    "p0_srv_w", "k_lp_w", "k_hp_w", "lp_share", "gamma", "power_scale", "dt",
+    "horizon", "a_hp", "a_lp", "svc_hp", "svc_lp", "total_budget")
 
 _MODEL_FIELD = dict(t1_buf="t1_buffer", t2_buf="t2_buffer",
                     lp_t1="lp_freq_t1", lp_t2="lp_freq_t2",
@@ -693,10 +708,10 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
         p_obs = jnp.where(p <= 1.0, jnp.minimum(p_ext, 1.0 - 1e-9), p_ext)
         return dict(c, hist_t=ht, hist_p=hp), p_obs
 
-    def run_scenario(occ60_all, consts, xs):
+    def run_scenario(occ60_all, srv_all, consts, xs):
         T, R, D, S = cfg.T, cfg.R, cfg.D, cfg.S
 
-        def step_for(occ60):
+        def step_for(occ60, consts):
             def step(c, x):
                 k, t, ii, iw, alive, bscale = x
                 slot = k % D
@@ -764,7 +779,7 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
                 return c, ys
             return step
 
-        def run_member(occ60):
+        def run_member(occ60, n_srv):
             carry = dict(
                 f_lp=jnp.ones(R), f_hp=jnp.ones(R),
                 ring=jnp.full((R, D, 2), jnp.nan),
@@ -778,7 +793,13 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
             if cfg.predictive:
                 carry.update(hist_t=jnp.zeros((R, cfg.W)),
                              hist_p=jnp.zeros((R, cfg.W)))
-            final, ys = lax.scan(step_for(occ60), carry, xs)
+            # the member's fleet folds into power_scale before the loop, so
+            # a step multiplies by one loop-invariant factor as it does for
+            # a scalar fleet size; power_scale * n_servers * (...) keeps
+            # its order and its bits (x * 1.0 == x)
+            fleet = consts._replace(power_scale=consts.power_scale * n_srv,
+                                    n_servers=1.0)
+            final, ys = lax.scan(step_for(occ60, fleet), carry, xs)
             out = dict(nbr=final["nbr"], peak=final["peak"],
                        mean=final["fsum"] / T, imp=final["imp"])
             i = 0
@@ -791,23 +812,24 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
             return out
 
         if cfg.chunk <= 0:
-            return jax.vmap(run_member)(occ60_all)
+            return jax.vmap(run_member)(occ60_all, srv_all)
         # bounded-memory tails: scan over member blocks so the in-flight
         # working set is one block's state, not all N members' at once
         N = occ60_all.shape[0]
-        blocked = occ60_all.reshape(
-            (N // cfg.chunk, cfg.chunk) + occ60_all.shape[1:])
+        blocked = [a.reshape((N // cfg.chunk, cfg.chunk) + a.shape[1:])
+                   for a in (occ60_all, srv_all)]
         _, outs = lax.scan(
-            lambda _, blk: (None, jax.vmap(run_member)(blk)), None, blocked)
+            lambda _, blk: (None, jax.vmap(run_member)(*blk)), None, blocked)
         return jax.tree_util.tree_map(
             lambda a: a.reshape((N,) + a.shape[2:]), outs)
 
     # the jit takes this function's name: the program is ``jit_tick_scan``
-    def tick_scan(occ60_g, consts_g, t_g, ii_g, iw_g, alive_g, bscale_g, ks):
+    def tick_scan(occ60_g, srv_g, consts_g, t_g, ii_g, iw_g, alive_g,
+                  bscale_g, ks):
         _TRACE_EVENTS.append(cfg)
 
-        def scenario(occ60_all, consts, t, ii, iw, alive, bscale):
-            return run_scenario(occ60_all, consts,
+        def scenario(occ60_all, srv_all, consts, t, ii, iw, alive, bscale):
+            return run_scenario(occ60_all, srv_all, consts,
                                 (ks, t, ii, iw, alive, bscale))
 
         # scenario axis on top of the member axis: one program, M scenarios.
@@ -815,21 +837,23 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
         # _geometry_key), hence identical across the bucket: in_axes=None
         # keeps the per-tick occ60 interpolation a dynamic-slice instead of
         # an M-batched gather (~1.5x per-member cost on CPU at M=4).
-        return jax.vmap(scenario, in_axes=(0, 0, None, None, None, 0, 0))(
-            occ60_g, consts_g, t_g, ii_g, iw_g, alive_g, bscale_g)
+        return jax.vmap(scenario,
+                        in_axes=(0, 0, 0, None, None, None, 0, 0))(
+            occ60_g, srv_g, consts_g, t_g, ii_g, iw_g, alive_g, bscale_g)
 
     fn = tick_scan
     if mesh is not None:
-        # shard the member axis (dim 1 everywhere) over the mesh's "data"
-        # axis; constants/timelines replicate. Each device runs the whole
-        # scan on its member shard — no cross-device collectives in the hot
-        # loop, so throughput scales with device count.
+        # shard the member axis (dim 1 of the occupancy and the fleet
+        # sizes) over the mesh's "data" axis; constants/timelines
+        # replicate. Each device runs the whole scan on its member shard —
+        # no cross-device collectives in the hot loop, so throughput scales
+        # with device count.
         from jax.sharding import PartitionSpec
         member = PartitionSpec(None, "data")
         rep = PartitionSpec()
         fn = jax.shard_map(
             tick_scan, mesh=mesh,
-            in_specs=(member, rep, rep, rep, rep, rep, rep, rep),
+            in_specs=(member, member, rep, rep, rep, rep, rep, rep, rep),
             out_specs=member, check_vma=False)
     # donating the occupancy grid lets XLA reuse its buffer for outputs on
     # accelerators; the CPU backend has no donation and would only warn
@@ -895,9 +919,11 @@ def _plan_bucket(models: Sequence[TickModel], *, keep_series: bool,
 def _bucket_operands(models: Sequence[TickModel], idx: np.ndarray) -> tuple:
     """The runner's operands for one bucket, as host arrays (float64 where
     the program computes in float64). Per-scenario constants stack on a
-    leading ``[M]`` axis; the tick grid (``t``/``ii``/``iw``) is shared
-    across the bucket by construction (geometry-keyed) and passes unbatched
-    so the runner's scenario vmap broadcasts it."""
+    leading ``[M]`` axis, and each member's fleet size rides beside its
+    occupancy as ``[M, N]``, padded with the same ``idx``; the tick grid
+    (``t``/``ii``/``iw``) is shared across the bucket by construction
+    (geometry-keyed) and passes unbatched so the runner's scenario vmap
+    broadcasts it."""
     m0 = models[0]
 
     def f64(vals):
@@ -907,8 +933,10 @@ def _bucket_operands(models: Sequence[TickModel], idx: np.ndarray) -> tuple:
     consts = _Consts(
         **{name: f64([_model_const(m, name) for m in models])
            for name in _CONST_SCALARS},
+        n_servers=None,
         row_budget=f64(np.stack([m.row_budget_w for m in models])))
-    return (np.stack([m.occ60[idx] for m in models]), consts,
+    return (np.stack([m.occ60[idx] for m in models]),
+            np.stack([m.servers()[idx] for m in models]), consts,
             f64(m0.tick_times()), np.asarray(i_idx, dtype=np.int32), f64(i_w),
             f64(np.stack([m.alive for m in models])),
             f64(np.stack([m.budget_scale for m in models])),
@@ -1005,6 +1033,11 @@ def _run_pallas(model: TickModel, keep_series: bool) -> BatchedRun:
     using the *same expressions* as the oracle (elementwise, so those
     planes are bit-identical by construction and the differential gate
     pins the kernel's brake sets / power series)."""
+    if model.member_servers is not None:
+        raise ValueError(
+            "engine='pallas' runs one fleet size per model; "
+            f"{model.base_name!r} stacks the members of several candidate "
+            "fleets (use engine='jax' or the numpy oracle)")
     if model.predictive:
         raise ValueError(
             "engine='pallas' runs the non-predictive PolcaPolicy tick loop; "
@@ -1271,3 +1304,92 @@ def run_batched_grid(specs: Sequence[EnsembleSpec], *,
                                     member_stats=flags[i][2])
                 for i, ((m, mem, budget), run) in enumerate(zip(lowered,
                                                                 runs))]
+
+
+# ---------------------------------------------------------------------------
+# candidate rounds: the fleets of one planner decision in one scan
+# ---------------------------------------------------------------------------
+
+# what the candidate fleets of one decision may differ in: each member
+# keeps its own occupancy, seed and fleet size
+_PER_MEMBER_FIELDS = ("n_members", "occ60", "n_servers", "member_servers",
+                      "seeds")
+
+
+def stack_tick_models(models: Sequence[TickModel]) -> TickModel:
+    """One TickModel whose member axis holds every model's members, in
+    order, each with its own fleet size (``member_servers``).
+
+    The models must agree in everything but their members: the candidate
+    fleets of one ``plan_capacity`` decision do, since they share the
+    scenario, the seeds and the pinned budget. Running the stack and
+    slicing it back (:func:`split_run`) gives each model's own run."""
+    m0 = models[0]
+    for m in models[1:]:
+        for f in dataclasses.fields(TickModel):
+            if f.name in _PER_MEMBER_FIELDS:
+                continue
+            a, b = getattr(m0, f.name), getattr(m, f.name)
+            if not (np.array_equal(a, b) if isinstance(a, np.ndarray)
+                    else a == b):
+                raise ValueError(
+                    f"cannot stack tick models that differ in {f.name!r} "
+                    f"({m0.base_name!r} vs {m.base_name!r})")
+    return dataclasses.replace(
+        m0, n_members=sum(m.n_members for m in models),
+        occ60=np.concatenate([m.occ60 for m in models]),
+        member_servers=np.concatenate([m.servers() for m in models]),
+        seeds=tuple(s for m in models for s in m.seeds))
+
+
+def split_run(run: BatchedRun, models: Sequence[TickModel]
+              ) -> List[BatchedRun]:
+    """The run of a stack of ``models`` cut back into one run per model,
+    each with its own model."""
+    planes = {f.name: getattr(run, f.name)
+              for f in dataclasses.fields(BatchedRun)
+              if f.name not in ("engine", "model")}
+    out, lo = [], 0
+    for m in models:
+        hi = lo + m.n_members
+        out.append(BatchedRun(engine=run.engine, model=m, **{
+            k: (None if v is None else v[lo:hi]) for k, v in planes.items()}))
+        lo = hi
+    return out
+
+
+def run_candidate_round(specs: Sequence[EnsembleSpec], *, budget_w: float,
+                        n_lanes: int = 0,
+                        keep_series: Optional[bool] = None,
+                        keep_brake_fire: Optional[bool] = None,
+                        member_stats: Optional[bool] = None,
+                        member_chunk: Optional[int] = None,
+                        mesh=None) -> List[Callable[[], EnsembleResult]]:
+    """Evaluate candidate fleets of one decision in ONE jax-engine scan.
+
+    Each spec is lowered on its own; the models stack on the member axis,
+    in order, and run in one :func:`run_tick_model` call. Pads repeating
+    the first candidate lead the stack up to ``n_lanes`` candidates, so
+    that every round of a decision runs one program, and are dropped. The
+    memory flags come from one candidate's model, not the stack's, so each
+    candidate's result is the one :func:`run_batched_ensemble` gives it.
+    Returns one function per spec that assembles its EnsembleResult when
+    called: candidates nobody reads are never assembled."""
+    lowered = [lower_ensemble(s, budget_w=budget_w) for s in specs]
+    models = [m for m, _, _ in lowered]
+    keep_series, keep_fire, member_stats = _auto_flags(
+        models[0], keep_series, keep_brake_fire, member_stats)
+    pads = max(0, n_lanes - len(lowered))
+    stacked = lowered[:1] * pads + lowered
+    run = run_tick_model(
+        stack_tick_models([m for m, _, _ in stacked]),
+        [sc for _, mem, _ in stacked for sc in mem], engine="jax",
+        keep_series=keep_series, keep_brake_fire=keep_fire,
+        member_chunk=member_chunk, mesh=mesh)
+    runs = split_run(run, models[:1] * pads + models)[pads:]
+
+    def assemble(i: int):
+        model, members, budget = lowered[i]
+        return lambda: _to_ensemble_result(model, members, budget, runs[i],
+                                           member_stats=member_stats)
+    return [assemble(i) for i in range(len(lowered))]

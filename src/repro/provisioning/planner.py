@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 from repro.chaos.faults import FaultSpec
 from repro.core.slo import DEFAULT_SLO, SLO
@@ -163,13 +163,19 @@ def plan_capacity(base: Scenario, *,
 
     ``engine`` selects the ensemble backend per :func:`run_ensemble` —
     ``"jax"`` is the dense-tail mode that makes 10^3+-seed probes (and so
-    the CVaR gate) affordable. On that engine the probe loop compiles ONE
-    device program for the whole bisection: per-scenario scalars
-    (``n_servers``, thresholds, budgets) are traced operands, so probes
-    differing only in fleet size / pinned budget hit the jit cache
+    the CVaR gate) affordable. On that engine the search compiles ONE
+    device program for the whole decision: per-scenario scalars
+    (thresholds, budgets) and each member's fleet size are traced operands
     (regression-asserted via ``batched.jax_trace_count`` in
     ``tests/test_grid_engine.py``), and the base occupancy curves are
-    cached across probes (only the fleet-scaled CLT jitter is recomputed).
+    cached across candidates (only the fleet-scaled CLT jitter is
+    recomputed). Where a probe leaves most of a member block idle
+    (``n_seeds`` at most half of ``batched._AUTO_CHUNK_MEMBERS``), the jax
+    engine runs speculative rounds: one scan evaluates every candidate
+    the bisection could visit next, as many as fill the block
+    (:func:`batched.run_candidate_round`), and the bisection replays over
+    the verdicts. The probes, their order and the decision are the
+    sequential search's, bit for bit; only the number of scans changes.
     ``engine_opts`` forward to :func:`run_ensemble` (``member_chunk``,
     ``mesh``, ``member_stats``, ...). ``constraints.survive`` requires the
     event-driven ``"numpy"`` engine (the chaos injector rides the
@@ -200,15 +206,52 @@ def plan_capacity(base: Scenario, *,
                 f"for")
     budget = resolve_ensemble_budget(base) if budget_w is None else float(budget_w)
     probes: List[PlanPoint] = []
+    rec = get_recorder()
 
-    def probe(k: int) -> PlanPoint:
-        sc = base.with_fleet(added_frac=k / n_prov).with_(budget=budget)
-        rec = get_recorder()
+    def candidate(k: int) -> Scenario:
+        return base.with_fleet(added_frac=k / n_prov).with_(budget=budget)
+
+    def spec(k: int) -> EnsembleSpec:
+        return EnsembleSpec(candidate(k), n_seeds=n_seeds, seed0=seed0,
+                            n_workers=n_workers, with_reference=True)
+
+    # speculative rounds (jax engine): one scan evaluates every candidate
+    # the bisection could visit next, as many as fill one flat member
+    # block; the bisection then replays over the verdicts
+    lanes = 1
+    if engine == "jax":
+        from repro.provisioning import batched
+
+        lanes = max(1, batched._AUTO_CHUNK_MEMBERS // n_seeds)
+    evaluated: Dict[int, Callable[[], EnsembleResult]] = {}
+    width = 0  # candidates of the first round; later rounds pad to it
+
+    def run_round(ks: List[int]) -> None:
+        nonlocal width
+        width = width or len(ks)
+        with rec.span("planner/round", scenario=base.name,
+                      candidates=len(ks), members=width * n_seeds):
+            evaluated.update(zip(ks, batched.run_candidate_round(
+                [spec(k) for k in ks], budget_w=budget, n_lanes=width,
+                **engine_opts)))
+        if rec.enabled:
+            rec.counter("planner_rounds_total")
+            rec.counter("planner_candidates_total", float(len(ks)))
+
+    def probe(k: int, lo: int, hi: int) -> PlanPoint:
+        """The verdict on fleet ``base + k`` while the bisection's bracket
+        is ``[lo, hi]``."""
+        if lanes > 1 and k not in evaluated:
+            # farthest first: ``k``, which the bisection reads now, holds
+            # the scan's last lanes
+            run_round(_round_candidates(lo, hi, lanes,
+                                        first=not evaluated)[::-1])
         with rec.span("planner/probe", scenario=base.name, added=k):
-            ens = run_ensemble(EnsembleSpec(sc, n_seeds=n_seeds, seed0=seed0,
-                                            n_workers=n_workers,
-                                            with_reference=True),
-                               budget_w=budget, engine=engine, **engine_opts)
+            if lanes > 1:
+                ens = evaluated[k]()
+            else:
+                ens = run_ensemble(spec(k), budget_w=budget, engine=engine,
+                                   **engine_opts)
             brake_p = ens.brake_prob(constraints.max_brakes)
             slo_p = _violation_prob(ens, constraints.slo)
             cvar: Optional[float] = None
@@ -221,8 +264,9 @@ def plan_capacity(base: Scenario, *,
                 # difference vs `ens` is the fault, so the gate isolates it. No
                 # reference twins — the gate is brake-only.
                 fens = run_ensemble(
-                    EnsembleSpec(sc.with_(faults=survive), n_seeds=n_seeds,
-                                 seed0=seed0, n_workers=n_workers),
+                    EnsembleSpec(candidate(k).with_(faults=survive),
+                                 n_seeds=n_seeds, seed0=seed0,
+                                 n_workers=n_workers),
                     budget_w=budget)
                 fault_p = fens.brake_prob(constraints.max_fault_brakes)
         pt = PlanPoint(
@@ -251,21 +295,41 @@ def plan_capacity(base: Scenario, *,
         return pt
 
     hi = max(1, int(math.floor(n_prov * max_added_frac)))
-    top = probe(hi)
+    top = probe(hi, 0, hi)
     if top.feasible:
         return PlanResult(base.name, n_prov, budget, hi, probes, capped=True)
-    bottom = probe(0)
+    bottom = probe(0, 0, hi)
     if not bottom.feasible:
         return PlanResult(base.name, n_prov, budget, 0, probes,
                           feasible_at_zero=False)
     lo = 0
     while hi - lo > 1:
         mid = (lo + hi) // 2
-        if probe(mid).feasible:
+        if probe(mid, lo, hi).feasible:
             lo = mid
         else:
             hi = mid
     return PlanResult(base.name, n_prov, budget, lo, probes)
+
+
+def _round_candidates(lo: int, hi: int, lanes: int, *,
+                      first: bool) -> List[int]:
+    """Up to ``lanes`` fleet sizes the bisection over ``[lo, hi]`` could
+    visit next, nearest first: on a decision's first round ``hi`` and
+    ``lo`` themselves, then the midpoints of the bisection tree, level by
+    level. The tree holds every integer strictly inside the bracket once,
+    so a bracket of at most ``lanes`` integers is evaluated whole."""
+    out = [hi, lo] if first else []
+    level = [(lo, hi)]
+    while level and len(out) < lanes:
+        deeper = []
+        for a, b in level:
+            if b - a > 1:
+                mid = (a + b) // 2
+                out.append(mid)
+                deeper += [(a, mid), (mid, b)]
+        level = deeper
+    return out[:lanes]
 
 
 def plan_controller_comparison(base: Scenario,

@@ -31,8 +31,14 @@ from repro.provisioning.batched import (
     lower_ensemble,
     run_batched_ensemble,
     run_tick_model,
+    split_run,
+    stack_tick_models,
 )
-from repro.provisioning.montecarlo import EnsembleSpec, run_ensemble
+from repro.provisioning.montecarlo import (
+    EnsembleSpec,
+    resolve_ensemble_budget,
+    run_ensemble,
+)
 from repro.provisioning.planner import RiskConstraints, plan_capacity
 
 HALF_HOUR = 1800.0
@@ -238,6 +244,43 @@ def test_planner_decisions_identical_across_engines():
     for pa, pb in zip(a.probes, b.probes):
         np.testing.assert_allclose(pa.brake_prob, pb.brake_prob)
         np.testing.assert_allclose(pa.slo_cvar, pb.slo_cvar, rtol=1e-6)
+
+
+@pytest.mark.parametrize("engine", ["jax", "numpy"])
+def test_stacked_candidates_split_back_to_their_own_runs(engine):
+    """The candidate fleets of one decision stacked on the member axis and
+    run once: each candidate's slice is its own run, bit for bit, on the
+    device program and on the oracle."""
+    sc = parity_scenario(occ_peak=0.97, power_scale=1.2,
+                         duration_s=HALF_HOUR, n_provisioned=10,
+                         added_frac=0.0, policy="polca-predictive")
+    budget = resolve_ensemble_budget(sc)
+    lowered = [lower_ensemble(EnsembleSpec(sc.with_fleet(added_frac=k / 10),
+                                           n_seeds=3, seed0=11),
+                              budget_w=budget) for k in (6, 0, 3)]
+    models = [m for m, _, _ in lowered]
+    stacked = stack_tick_models(models)
+    assert stacked.n_members == 9
+    np.testing.assert_array_equal(stacked.servers(),
+                                  np.repeat([16.0, 10.0, 13.0], 3))
+    run = run_tick_model(stacked, [s for _, mem, _ in lowered for s in mem],
+                         engine=engine)
+    assert run.n_brakes.sum() > 0, "the stack should brake"
+    for (model, members, _), got in zip(lowered, split_run(run, models)):
+        assert got.model is model
+        want = run_tick_model(model, members, engine=engine)
+        for name in ("brake_fire", "n_brakes", "peak_frac", "mean_frac",
+                     "impacts_hp", "impacts_lp", "total_frac", "row_w"):
+            np.testing.assert_array_equal(getattr(got, name),
+                                          getattr(want, name), err_msg=name)
+
+
+def test_stacking_refuses_models_that_differ_beyond_members():
+    sc = parity_scenario(duration_s=HALF_HOUR)
+    a, _, _ = lower_ensemble(EnsembleSpec(sc, n_seeds=2), budget_w=1e6)
+    b, _, _ = lower_ensemble(EnsembleSpec(sc, n_seeds=2), budget_w=2e6)
+    with pytest.raises(ValueError, match="row_budget_w"):
+        stack_tick_models([a, b])
 
 
 def test_brakes_actually_fire_and_match():

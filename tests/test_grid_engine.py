@@ -23,6 +23,7 @@ from repro.provisioning.batched import (
     run_batched_ensemble,
     run_batched_grid,
     run_tick_model,
+    stack_tick_models,
 )
 from repro.provisioning.montecarlo import (
     EnsembleSpec,
@@ -99,17 +100,41 @@ def test_sharded_and_chunked_compose():
     _assert_results_identical(base, both)
 
 
-def test_plan_capacity_probe_count_does_not_multiply_compiles():
-    """Satellite-6 regression: per-scenario scalars are traced operands, so
-    a whole bisection (fleet size varies, budget pinned) compiles once."""
+@pytest.mark.parametrize("lanes", [None, 3])
+def test_plan_capacity_probe_count_does_not_multiply_compiles(lanes,
+                                                              monkeypatch):
+    """Per-scenario scalars and fleet sizes are traced operands, so a whole
+    decision (fleet size varies, budget pinned) compiles at most once, and
+    runs one scan per speculative round: all candidates in one round, or
+    (3 candidates a round) every round padded to the first one's shape."""
+    from repro.obs.metrics import MetricsRecorder, recording
+    from repro.provisioning import batched
+
+    if lanes is not None:
+        monkeypatch.setattr(batched, "_AUTO_CHUNK_MEMBERS", lanes * 4)
+    scans = []
+    orig = batched.run_tick_model
+
+    def counting(model, *a, **kw):
+        scans.append(model.n_members)
+        return orig(model, *a, **kw)
+    monkeypatch.setattr(batched, "run_tick_model", counting)
     sc = parity_scenario(generator="diurnal")
     t0 = jax_trace_count()
-    plan = plan_capacity(sc, n_seeds=4, engine="jax")
+    rec = MetricsRecorder()
+    with recording(rec):
+        plan = plan_capacity(sc, n_seeds=4, engine="jax")
     assert len(plan.probes) >= 3, "bisection too shallow to regression-test"
     assert jax_trace_count() - t0 <= 1, (
         f"{len(plan.probes)} probes retraced the engine "
         f"{jax_trace_count() - t0} times; scalar consts leaked back into "
         "the jit cache key")
+    rounds = rec.snapshot().counter_total("planner_rounds_total")
+    assert len(scans) == rounds  # one scan per round
+    if lanes is None:
+        assert rounds == 1
+    else:
+        assert rounds > 1 and scans == [lanes * 4] * len(scans)
 
 
 @pytest.mark.parametrize("generator", PARITY_GENERATORS)
@@ -129,6 +154,32 @@ def test_pallas_rejects_predictive():
         parity_scenario(policy="polca-predictive"), n_seeds=2))
     with pytest.raises(ValueError, match="predictive"):
         run_tick_model(model, members, engine="pallas")
+
+
+def test_plan_capacity_rounds_invariant_to_mesh():
+    """A round's stacked members shard over the "data" mesh, each with its
+    own fleet size, padded to the device multiple: the decision and every
+    probe's numbers equal the one-device plan's."""
+    sc = parity_scenario(generator="diurnal", n_provisioned=9)
+    one = plan_capacity(sc, n_seeds=3, engine="jax", keep_ensembles=True)
+    four = plan_capacity(sc, n_seeds=3, engine="jax", keep_ensembles=True,
+                         mesh=data_mesh(4))
+    assert four.safe_added_servers == one.safe_added_servers
+    assert [p.added_servers for p in four.probes] == \
+        [p.added_servers for p in one.probes]
+    for a, b in zip(four.probes, one.probes):
+        _assert_results_identical(a.ensemble, b.ensemble)
+
+
+def test_pallas_rejects_stacked_model():
+    sc = parity_scenario()
+    models = [lower_ensemble(EnsembleSpec(sc.with_fleet(added_frac=f),
+                                          n_seeds=2), budget_w=1e6)
+              for f in (0.0, 0.1)]
+    stacked = stack_tick_models([m for m, _, _ in models])
+    with pytest.raises(ValueError, match="candidate fleets"):
+        run_tick_model(stacked, models[0][1] + models[1][1],
+                       engine="pallas")
 
 
 def test_dense_member_stats_equivalent():

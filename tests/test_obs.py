@@ -252,13 +252,14 @@ class _Annotations:
 
 def _batched_nbytes(model, n_dispatches):
     """Bytes each way of ``n_dispatches`` runs of ``model`` on the jax
-    engine, from its shapes: operands in, outputs out (brake plane and
-    series kept, members unpadded)."""
+    engine, from its shapes: operands in (occupancy and fleet size per
+    member), outputs out (brake plane and series kept, members
+    unpadded)."""
     from repro.provisioning import batched
 
     N, R, T, S = model.n_members, model.n_rows, model.n_ticks, model.n_slots
     T60 = model.occ60.shape[2]
-    h2d = (8 * N * R * T60 + 8 * len(batched._CONST_SCALARS) + 8 * R
+    h2d = (8 * N * R * T60 + 8 * N + 8 * len(batched._CONST_SCALARS) + 8 * R
            + (8 + 4 + 8 + 4) * T + 2 * 8 * T * R)
     d2h = (4 * N * R + 8 * N + 8 * N + 8 * N * S * R * 2 + N * T * R
            + 8 * N * T + 8 * N * T * R)
@@ -267,8 +268,9 @@ def _batched_nbytes(model, n_dispatches):
 
 def test_batched_spans_nest_once_per_dispatch(monkeypatch):
     """Each jax-engine dispatch opens the five ``batched/*`` spans, in
-    order, directly inside its ``mc/run_batched``; on the profiler's clock
-    they are ``polca/<name>`` annotations."""
+    order, directly inside its ``mc/run_batched``, or inside the
+    ``planner/round`` that runs a plan's candidates; on the profiler's
+    clock they are ``polca/<name>`` annotations."""
     import jax
 
     from repro.provisioning.batched import run_batched_ensemble
@@ -282,19 +284,53 @@ def test_batched_spans_nest_once_per_dispatch(monkeypatch):
                              engine="jax")
         plan = _plan(sc)
     assert len(plan.probes) == 3
-    n_dispatch = 1 + len(plan.probes)
-    assert ann.children("polca/mc/run_batched") == \
-        ["polca/" + n for n in BATCHED_SPANS] * n_dispatch
-    runs = [around for n, around in ann.opened if n == "polca/mc/run_batched"]
-    assert runs == [()] + [("polca/planner/probe",)] * len(plan.probes)
+    one_dispatch = ["polca/" + n for n in BATCHED_SPANS]
+    assert ann.children("polca/mc/run_batched") == one_dispatch
+    # the plan's three candidates are one round, before its first probe
+    assert ann.children("polca/planner/round") == one_dispatch
+    opened = [(n, around) for n, around in ann.opened
+              if n in ("polca/mc/run_batched", "polca/planner/round",
+                       "polca/planner/probe")]
+    assert opened == [("polca/mc/run_batched", ()),
+                      ("polca/planner/round", ())] + \
+        [("polca/planner/probe", ())] * len(plan.probes)
     assert not ann.stack
     spans = {name: s.count for (name, _), s in rec.snapshot().spans.items()
              if name.startswith("batched/")}
-    assert spans == {n: n_dispatch for n in BATCHED_SPANS}
+    assert spans == {n: 2 for n in BATCHED_SPANS}
+
+
+def test_planner_round_counters_for_a_one_round_decision():
+    """A decision whose candidates fit one member block is one round: one
+    ``planner/round`` span labelled with its candidates and members, and
+    the round counters beside the per-probe ones, which stay one per probe
+    on the bisection's path."""
+    sc = _batched_scenario()
+    rec = MetricsRecorder()
+    with recording(rec):
+        plan = _plan(sc)
+    snap = rec.snapshot()
+    assert [p.added_servers for p in plan.probes] == [2, 0, 1]
+    assert snap.counter_total("planner_rounds_total") == 1
+    assert snap.counter_total("planner_candidates_total") == 3
+    assert snap.counter_total("planner_probes_total") == 3
+    assert snap.counters[("planner_probes_total",
+                          (("outcome", "feasible"),))] == 2
+    rounds = {labels: s.count for (name, labels), s in snap.spans.items()
+              if name == "planner/round"}
+    assert rounds == {(("candidates", "3"), ("members", "12"),
+                       ("scenario", sc.name)): 1}
+    assert sum(s.count for (name, _), s in snap.spans.items()
+               if name == "planner/probe") == 3
+    assert len(snap.events_of("planner", "probe")) == 3
 
 
 def test_batched_byte_counters_match_the_shapes():
-    from repro.provisioning.batched import _auto_flags, lower_ensemble
+    from repro.provisioning.batched import (
+        _auto_flags,
+        lower_ensemble,
+        stack_tick_models,
+    )
 
     sc = _batched_scenario()
     spec = EnsembleSpec(sc, n_seeds=3, seed0=7)
@@ -309,16 +345,17 @@ def test_batched_byte_counters_match_the_shapes():
     h2d, d2h = _batched_nbytes(model, 1)
     assert snap.counter_total("batched_h2d_bytes_total") == h2d
     assert snap.counter_total("batched_d2h_bytes_total") == d2h
-    # a plan's probes: one dispatch each, at the probe's own fleet size
+    # a plan's three candidates: one dispatch, each at its own fleet size
     rec = MetricsRecorder()
     with recording(rec):
         plan = _plan(sc)
-    want = np.sum([_batched_nbytes(lower_ensemble(EnsembleSpec(
-        sc.with_fleet(added_frac=p.added_frac), n_seeds=4, seed0=42))[0], 1)
-        for p in plan.probes], axis=0)
+    assert sorted(p.added_servers for p in plan.probes) == [0, 1, 2]
+    want = _batched_nbytes(stack_tick_models([lower_ensemble(EnsembleSpec(
+        sc.with_fleet(added_frac=p.added_frac), n_seeds=4, seed0=42))[0]
+        for p in plan.probes]), 1)
     snap = rec.snapshot()
-    assert [snap.counter_total("batched_h2d_bytes_total"),
-            snap.counter_total("batched_d2h_bytes_total")] == want.tolist()
+    assert (snap.counter_total("batched_h2d_bytes_total"),
+            snap.counter_total("batched_d2h_bytes_total")) == want
 
 
 def test_batched_engine_bit_parity_recorder_on_vs_off():

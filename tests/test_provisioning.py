@@ -4,7 +4,7 @@ batched-vs-sequential Monte-Carlo bit-parity, planner monotonicity."""
 import numpy as np
 import pytest
 
-from repro.core.slo import SLO
+from repro.core.slo import DEFAULT_SLO, SLO
 from repro.core.traces import (
     get_occupancy_generator,
     list_occupancy_generators,
@@ -335,6 +335,111 @@ def test_planner_survive_requires_numpy_engine():
     with pytest.raises(ValueError, match="engine='numpy'"):
         plan_capacity(routed, n_seeds=4, engine="jax",
                       constraints=RiskConstraints(survive=survive))
+
+
+# ---------------------------------------------------- speculative rounds
+# (occ_peak, power_scale, n_seeds, lanes): lanes=None keeps the engine's
+# own member block, so n_seeds alone sets how many candidates a round holds
+ROUND_CASES = {
+    "capped": (0.35, 0.90, 4, None),  # the top of the range is feasible
+    "infeasible_at_zero": (0.99, 2.2, 4, None),
+    "full_bisection": (0.90, 1.08, 4, None),  # all 13 candidates, 1 round
+    "multi_round": (0.95, 1.00, 4, 3),  # 3 candidates a round, padded
+    "one_candidate_a_round": (0.90, 1.08, 512, None),  # today's probes
+}
+
+
+def _sequential_plan(base, *, n_seeds, seed0, max_added_frac):
+    """The bisection of ``plan_capacity`` over one ``run_ensemble`` per
+    probed fleet, for constraints that admit a fleet where no member
+    brakes. Returns (safe, capped, feasible_at_zero, [(k, ensemble)])."""
+    n_prov = base.fleet.n_provisioned
+    budget = resolve_ensemble_budget(base)
+    path = []
+
+    def feasible(k):
+        sc = base.with_fleet(added_frac=k / n_prov).with_(budget=budget)
+        ens = run_ensemble(EnsembleSpec(sc, n_seeds=n_seeds, seed0=seed0,
+                                        with_reference=True),
+                           budget_w=budget, engine="jax")
+        path.append((k, ens))
+        return ens.brake_prob(0) <= 1e-12
+
+    hi = max(1, int(np.floor(n_prov * max_added_frac)))
+    if feasible(hi):
+        return hi, True, True, path
+    if not feasible(0):
+        return 0, False, False, path
+    lo = 0
+    while hi - lo > 1:
+        mid = (lo + hi) // 2
+        lo, hi = (mid, hi) if feasible(mid) else (lo, mid)
+    return lo, False, True, path
+
+
+@pytest.mark.parametrize("case", sorted(ROUND_CASES))
+def test_planner_rounds_equal_the_sequential_search(case, monkeypatch):
+    """plan_capacity(engine="jax") evaluates candidates in speculative
+    rounds and replays the bisection over them: the decision, the probe
+    path and every probe's ensemble are the sequential search's, bit for
+    bit, whether a round holds the whole range, part of it, or one
+    candidate (n_seeds >= the member block: no rounds at all)."""
+    from conftest import parity_scenario
+    from repro.obs.metrics import MetricsRecorder, recording
+    from repro.provisioning import batched
+
+    occ, scale, n_seeds, lanes = ROUND_CASES[case]
+    if lanes is not None:
+        monkeypatch.setattr(batched, "_AUTO_CHUNK_MEMBERS", lanes * n_seeds)
+    base = parity_scenario(occ_peak=occ, power_scale=scale,
+                           duration_s=1800.0, n_provisioned=20,
+                           added_frac=0.0)
+    kw = dict(n_seeds=n_seeds, seed0=42, max_added_frac=0.6)
+    rec = MetricsRecorder()
+    with recording(rec):
+        plan = plan_capacity(base, engine="jax", keep_ensembles=True,
+                             constraints=RiskConstraints(
+                                 max_brakes=0, max_slo_violation_prob=1.0),
+                             **kw)
+    safe, capped, at_zero, path = _sequential_plan(base, **kw)
+    assert (plan.safe_added_servers, plan.capped, plan.feasible_at_zero) == \
+        (safe, capped, at_zero)
+    assert [p.added_servers for p in plan.probes] == [k for k, _ in path]
+    for p, (k, want) in zip(plan.probes, path):
+        got = p.ensemble
+        assert p.feasible == (want.brake_prob(0) <= 1e-12)
+        assert p.brake_prob == want.brake_prob(0)
+        assert p.slo_violation_prob == want.slo_violation_prob(DEFAULT_SLO)
+        for name in ("brake_counts", "peak_fracs", "mean_fracs",
+                     "power_t", "power_frac"):
+            np.testing.assert_array_equal(getattr(got, name),
+                                          getattr(want, name), err_msg=name)
+        assert [(m.stats.hp_impacts, m.stats.lp_impacts)
+                for m in got.members] == \
+            [(m.stats.hp_impacts, m.stats.lp_impacts) for m in want.members]
+    snap = rec.snapshot()
+    want_rounds = {"capped": 1, "infeasible_at_zero": 1, "full_bisection": 1,
+                   "multi_round": 3, "one_candidate_a_round": 0}[case]
+    assert snap.counter_total("planner_rounds_total") == want_rounds
+    assert snap.counter_total("planner_probes_total") == len(plan.probes)
+    if case == "one_candidate_a_round":
+        assert snap.counter_total("planner_candidates_total") == 0
+    else:
+        assert snap.counter_total("planner_candidates_total") >= \
+            len(plan.probes)
+
+
+def test_round_candidates_cover_the_bracket_nearest_first():
+    from repro.provisioning.planner import _round_candidates
+
+    # a bracket that fits a round is evaluated whole, ends first
+    assert sorted(_round_candidates(0, 24, 64, first=True)) == \
+        list(range(25))
+    assert _round_candidates(0, 24, 64, first=True)[:5] == [24, 0, 12, 6, 18]
+    # a later round: the top levels of the bracket, breadth first
+    assert _round_candidates(6, 12, 3, first=False) == [9, 7, 10]
+    assert _round_candidates(10, 12, 3, first=False) == [11]
+    assert _round_candidates(0, 12, 2, first=True) == [12, 0]
 
 
 # ---------------------------------------------------------------- traces

@@ -55,18 +55,30 @@ class PowerHierarchy:
     ``parent[i]`` is the parent node index (``-1`` for the root);
     ``node_budget_w[i]`` the node's power budget in watts (mutable — the
     fleet controller re-divides interior budgets under ``scope="tree"``);
-    ``names[i]`` a human-readable label carried into telemetry and docs.
+    ``names[i]`` a human-readable label carried into telemetry and docs;
+    ``capacity_w[i]`` the node's physical rating in watts (a switchboard's
+    nameplate), which defaults to its budget. A rating below the sum of the
+    children's budgets is an oversubscribed node: the budgets stay the
+    conservative tree, and the rating is what the node's load is held to
+    when it is read out (``provisioning.batched`` counts the ticks over it).
     """
 
     def __init__(self, parent: Sequence[int], node_budget_w: Sequence[float],
-                 n_leaves: int, names: Optional[Sequence[str]] = None):
+                 n_leaves: int, names: Optional[Sequence[str]] = None,
+                 capacity_w: Optional[Sequence[float]] = None):
         self.parent = np.asarray(parent, dtype=int)
         self.node_budget_w = np.asarray(node_budget_w, dtype=float).copy()
+        self.capacity_w = np.asarray(
+            self.node_budget_w if capacity_w is None else capacity_w,
+            dtype=float).copy()
         self.n_leaves = int(n_leaves)
         self.n_nodes = len(self.parent)
         if len(self.node_budget_w) != self.n_nodes:
             raise ValueError(
                 f"{len(self.node_budget_w)} budgets for {self.n_nodes} nodes")
+        if len(self.capacity_w) != self.n_nodes:
+            raise ValueError(
+                f"{len(self.capacity_w)} ratings for {self.n_nodes} nodes")
         if not 0 < self.n_leaves <= self.n_nodes:
             raise ValueError(
                 f"n_leaves={self.n_leaves} out of range for {self.n_nodes} nodes")
@@ -95,6 +107,8 @@ class PowerHierarchy:
         # loss, thermal throttle) and the rebalancing controller clamps its
         # divisions to it — otherwise a tree-scope pass would "heal" the
         # fault by growing the derated subtree back on its next interval.
+        # (``capacity_w`` is the static nameplate rating the load is read
+        # against; ``node_cap_w`` the live ceiling a fault moves.)
         self.node_cap_w = np.full(self.n_nodes, np.inf)
 
         self.children: List[np.ndarray] = [
@@ -171,7 +185,8 @@ class PowerHierarchy:
     @classmethod
     def from_shape(cls, shape: Sequence[int], row_budget_w: Sequence[float], *,
                    level_names: Optional[Sequence[str]] = None,
-                   budget_fracs: Optional[Dict[str, float]] = None
+                   budget_fracs: Optional[Dict[str, float]] = None,
+                   level_capacity_w: Optional[Sequence[Optional[float]]] = None
                    ) -> "PowerHierarchy":
         """A uniform tree from root-down fan-outs: ``shape=(2, 2, 3)`` is a
         root with 2 children (PDU sets), each with 2 children (racks), each
@@ -184,6 +199,10 @@ class PowerHierarchy:
         planner-shaped budgets stay *conservative*: each node's budget is
         exactly the sum of its children's, so a derated rack shrinks its
         rows' budgets rather than promising watts the PDU can't deliver.
+
+        ``level_capacity_w`` rates the interior levels root-down in watts
+        (``capacity_w``); a level given as ``None``, or left out, is rated
+        at each node's budget.
         """
         shape = tuple(int(s) for s in shape)
         if not shape or any(s < 1 for s in shape):
@@ -203,6 +222,15 @@ class PowerHierarchy:
         if len(level_names) != len(shape):
             raise ValueError(f"{len(level_names)} level names for "
                              f"{len(shape)} interior levels")
+        ratings = tuple(level_capacity_w or ())
+        if len(ratings) > len(shape):
+            raise ValueError(f"{len(ratings)} level ratings for "
+                             f"{len(shape)} interior levels")
+        for r in ratings:
+            if r is not None and not (np.isfinite(r) and r > 0.0):
+                raise ValueError(
+                    f"level_capacity_w entries must be positive finite watts "
+                    f"or None, got {r!r}")
 
         # enumerate interior nodes per level, root-down; leaves come first in
         # the node index space, then the deepest interior level, ..., root
@@ -264,7 +292,11 @@ class PowerHierarchy:
                         else offsets[d + 1] + np.arange(j * shape[d],
                                                         (j + 1) * shape[d]))
                 budgets[node] = float(budgets[kids].sum())
-        return cls(parent, budgets, n_rows, names)
+        capacity = budgets.copy()
+        for d, r in enumerate(ratings):
+            if r is not None:
+                capacity[offsets[d]:offsets[d] + counts[d]] = float(r)
+        return cls(parent, budgets, n_rows, names, capacity)
 
     # -- views --------------------------------------------------------------
     @property

@@ -126,11 +126,19 @@ class HierarchySpec:
     carrying a HierarchySpec runs its fleet (or cluster) under this tree
     instead of the default two-level ``rows_per_rack`` split; with a
     ``ControllerSpec(scope="tree")`` the rebalancing controller re-divides
-    budgets recursively at every interior node."""
+    budgets recursively at every interior node.
+
+    ``level_capacity_w`` rates the interior levels root-down in watts (a
+    switchboard's nameplate; ``None`` for a level rated at its nodes'
+    budgets). It is kept apart from the budget tree: a rating below the
+    sum of a node's children oversubscribes that node, and the batched
+    engine counts each member's ticks over it (``EnsembleResult.
+    node_over_ticks``); POLCA still controls each row alone."""
 
     shape: Tuple[int, ...] = (2, 2)
     level_names: Optional[Tuple[str, ...]] = None
     budget_fracs: Dict[str, float] = field(default_factory=dict)
+    level_capacity_w: Optional[Tuple[Optional[float], ...]] = None
 
     @property
     def n_rows(self) -> int:
@@ -142,7 +150,8 @@ class HierarchySpec:
         from repro.core.hierarchy import PowerHierarchy
         return PowerHierarchy.from_shape(
             self.shape, row_budget_w, level_names=self.level_names,
-            budget_fracs=self.budget_fracs)
+            budget_fracs=self.budget_fracs,
+            level_capacity_w=self.level_capacity_w)
 
 
 @dataclass(frozen=True)
@@ -297,6 +306,8 @@ class Scenario:
             h["shape"] = tuple(h.get("shape", ()))
             if h.get("level_names") is not None:
                 h["level_names"] = tuple(h["level_names"])
+            if h.get("level_capacity_w") is not None:
+                h["level_capacity_w"] = tuple(h["level_capacity_w"])
             d["hierarchy"] = HierarchySpec(**h)
         if d.get("faults") is not None:
             d["faults"] = FaultSpec.from_dict(d["faults"])
@@ -496,6 +507,30 @@ SITE_SCENARIO_FAMILY: List[str] = [
     "site-rack-predictive",
     "site-tree-predictive",
 ]
+
+# A 56-row oversubscribed site: a production power tree as Wu et al.,
+# "Dynamo: Facebook's Data Center-Wide Power Management System" (ISCA 2016)
+# section 2 describes it -- main switchboards (MSB) rated 2.5 MW, each
+# feeding switchboards (SB) rated 1.25 MW -- whose leaves are POLCA's
+# evaluated row (fig14-plus30: 40 DGX A100 servers provisioned, 52 hosted,
+# Table 2's row budget, pinned in watts so that every row carries the
+# figure's own). Dynamo gives no fan-outs, so each below the root is the
+# parent's rating over its child's, rounded: 2 SBs per MSB (2.5 / 1.25 MW)
+# and 7 rows per SB (1.25 MW over Dynamo's 190 kW RPP, one RPP a row); the
+# 4 MSBs and the 10 MW root are assumed. 7 rows put 1.39 MW of row budgets
+# under a 1.25 MW SB, so at +30% per row the SBs carry the risk. The
+# budgets stay the conservative tree; the ratings are read out, not
+# enforced (POLCA caps each row alone). One day: the diurnal peak, where the
+# SBs bind, included.
+TABLE2_ROW_BUDGET_W = 199_225.07468596008  # fig14-plus30's resolved budget
+register_scenario(Scenario(
+    name="site56",
+    duration_s=DAY,
+    fleet=FleetSpec(n_provisioned=40, added_frac=0.30, n_rows=56),
+    hierarchy=HierarchySpec(shape=(4, 2, 7), level_names=("site", "msb", "sb"),
+                            level_capacity_w=(10.0e6, 2.5e6, 1.25e6)),
+    budget=TABLE2_ROW_BUDGET_W,
+))
 
 # Chaos scenarios (repro.chaos): the 12-row site under injected fault
 # timelines. Unlike the site-* family the site starts *healthy* (no

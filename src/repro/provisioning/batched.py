@@ -14,7 +14,11 @@ program (DESIGN.md §15):
   busy-power terms with the DVFS ``f^gamma`` law from
   ``core.power_model``), the POLCA thresholds/frequencies, fault timelines
   lowered to per-tick budget scales and row-alive masks, and the
-  ``PowerHierarchy`` node matrix for segment-sum folds.
+  ``PowerHierarchy`` node matrix for segment-sum folds. A tree whose
+  interior levels carry ratings (``HierarchySpec.level_capacity_w``) is
+  folded inside the tick loop: each member's row watts sum up the tree
+  every tick, and each interior node keeps its peak watts and its count of
+  ticks over its rating (:func:`_node_w`).
 
 * **Three backends, one contract** — ``engine="jax"`` runs the tick advance
   as a ``lax.scan`` over time ``vmap``-ed over members, with the
@@ -155,6 +159,11 @@ class TickModel:
     # hierarchy segment-sum fold (None = flat row accounting)
     node_matrix: Optional[np.ndarray] = field(default=None, repr=False)  # [n_nodes, R]
     node_names: Tuple[str, ...] = ()
+    # a rated tree, folded inside the tick loop: its root-down fan-outs and
+    # the ratings of its interior nodes, children before parents (the
+    # PowerHierarchy's order); () and None = no fold
+    node_shape: Tuple[int, ...] = ()
+    node_capacity_w: Optional[np.ndarray] = field(default=None, repr=False)  # [nodes]
     seeds: Tuple[int, ...] = ()
     # [N] fleet size of each member where the members of several candidate
     # fleets share one model (stack_tick_models); None = n_servers for all
@@ -197,6 +206,10 @@ class BatchedRun:
     total_frac: Optional[np.ndarray] = field(default=None, repr=False)  # [N, T]
     row_w: Optional[np.ndarray] = field(default=None, repr=False)  # [N, T, R]
     node_w: Optional[np.ndarray] = field(default=None, repr=False)  # [N, T, nodes]
+    # a rated tree's interior nodes (TickModel.node_shape): peak watts and
+    # ticks over the rating, per member
+    node_peak_w: Optional[np.ndarray] = field(default=None, repr=False)  # [N, nodes]
+    node_over_ticks: Optional[np.ndarray] = field(default=None, repr=False)  # [N, nodes]
 
     def brake_ticks(self) -> np.ndarray:
         """Sorted (member, tick, row) index triples of every brake firing —
@@ -413,6 +426,8 @@ def lower_ensemble(spec: EnsembleSpec, *, budget_w: Optional[float] = None
     hierarchy = None
     node_matrix = None
     node_names: Tuple[str, ...] = ()
+    node_shape: Tuple[int, ...] = ()
+    node_capacity_w = None
     base_budgets = row_budgets(sc, budget, server)
     if sc.hierarchy is not None:
         if sc.hierarchy.n_rows != fleet.n_rows:
@@ -425,6 +440,10 @@ def lower_ensemble(spec: EnsembleSpec, *, budget_w: Optional[float] = None
         for n in range(hierarchy.n_nodes):
             node_matrix[n, hierarchy.leaf_desc[n]] = 1.0
         node_names = tuple(hierarchy.names)
+        if sc.hierarchy.level_capacity_w is not None:
+            node_shape = tuple(sc.hierarchy.shape)
+            node_capacity_w = np.asarray(
+                hierarchy.capacity_w[hierarchy.n_leaves:], dtype=np.float64)
     else:
         row_budget = np.asarray(base_budgets, dtype=np.float64)
 
@@ -444,6 +463,7 @@ def lower_ensemble(spec: EnsembleSpec, *, budget_w: Optional[float] = None
         ring_depth=max(oob_ticks, brake_ticks) + 1,
         stride=stride, n_slots=math.ceil(n_ticks / stride),
         node_matrix=node_matrix, node_names=node_names,
+        node_shape=node_shape, node_capacity_w=node_capacity_w,
         seeds=tuple(spec.seeds()),
         **_policy_constants(sc), **_power_constants(sc))
     return model, members, budget
@@ -482,6 +502,23 @@ def _slo_step(model: TickModel, occ, f_lp, f_hp, backlog_hp, backlog_lp, xp):
     return backlog_hp, backlog_lp, imp_hp, imp_lp
 
 
+def _node_w(shape: Tuple[int, ...], row_w, xp):
+    """``[..., R]`` row watts -> ``[..., nodes]`` watts of the interior
+    nodes of the regular tree with root-down fan-outs ``shape``, children
+    before parents (the PowerHierarchy's order). Leaves under a node are
+    contiguous, so each level is a reshape and a sum over the one below."""
+    levels, x = [], row_w
+    for fan in reversed(shape):
+        x = xp.sum(x.reshape(x.shape[:-1] + (-1, fan)), axis=-1)
+        levels.append(x)
+    return xp.concatenate(levels, axis=-1)
+
+
+def _n_nodes(shape: Tuple[int, ...]) -> int:
+    """Interior nodes of the regular tree ``shape``."""
+    return sum(math.prod(shape[:d]) for d in range(len(shape)))
+
+
 def _interp_weights(model: TickModel) -> Tuple[np.ndarray, np.ndarray]:
     """Per-tick (left index, right weight) into the 60 s occupancy grid —
     precomputed once so both backends interpolate identically."""
@@ -511,6 +548,9 @@ def _run_oracle(model: TickModel, members: List[Scenario],
     total = np.zeros((N, T)) if keep_series else None
     row_w_out = np.zeros((N, T, R)) if keep_series else None
     total_budget = model.total_budget_w
+    fold = model.node_shape
+    node_peak = np.zeros((N, _n_nodes(fold)))
+    node_over = np.zeros((N, _n_nodes(fold)), dtype=np.int64)
 
     for m, member in enumerate(members):
         # the member's own fleet size where candidates share the model
@@ -541,6 +581,10 @@ def _run_oracle(model: TickModel, members: List[Scenario],
             if keep_series:
                 total[m, k] = frac
                 row_w_out[m, k] = rw
+            if fold:
+                nw = _node_w(fold, rw, np)
+                node_peak[m] = np.maximum(node_peak[m], nw)
+                node_over[m] += nw > model.node_capacity_w
             tick_budget = model.row_budget_w * model.budget_scale[k]
             p = rw / tick_budget
             lp_frac = _lp_power_w(pm, occ, f_lp, np) / tick_budget
@@ -571,10 +615,13 @@ def _run_oracle(model: TickModel, members: List[Scenario],
     node_w = None
     if keep_series and model.node_matrix is not None:
         node_w = np.einsum("ntr,mr->ntm", row_w_out, model.node_matrix)
-    return BatchedRun(engine="numpy", model=model, brake_fire=brake_fire,
-                      n_brakes=n_brakes, peak_frac=peak, mean_frac=mean,
-                      impacts_hp=imp_hp, impacts_lp=imp_lp, total_frac=total,
-                      row_w=row_w_out, node_w=node_w)
+    run = BatchedRun(engine="numpy", model=model, brake_fire=brake_fire,
+                     n_brakes=n_brakes, peak_frac=peak, mean_frac=mean,
+                     impacts_hp=imp_hp, impacts_lp=imp_lp, total_frac=total,
+                     row_w=row_w_out, node_w=node_w)
+    if fold:
+        run.node_peak_w, run.node_over_ticks = node_peak, node_over
+    return run
 
 
 # ---------------------------------------------------------------------------
@@ -605,6 +652,9 @@ class _JaxCfg(NamedTuple):
     keep_series: bool
     keep_fire: bool
     chunk: int  # member-block size for the inner lax.scan; 0 = plain vmap
+    # a rated tree's root-down fan-outs (TickModel.node_shape), folded in
+    # the loop; () compiles the program without the fold
+    fold: Tuple[int, ...] = ()
 
 
 class _Consts(NamedTuple):
@@ -640,6 +690,7 @@ class _Consts(NamedTuple):
     svc_lp: object
     total_budget: object
     row_budget: object
+    node_cap: object = None  # [nodes] / [M, nodes] ratings; None = no fold
 
 
 _CONST_SCALARS = (
@@ -722,7 +773,7 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
                 f_hp = jnp.where(has[:, 1], pend[:, 1], c["f_hp"])
                 ring = lax.dynamic_update_index_in_dim(
                     c["ring"], jnp.full((R, 2), jnp.nan), slot, axis=1)
-                occ = ((occ60[:, ii] * (1.0 - iw) + occ60[:, ii + 1] * iw)
+                occ = ((occ60[:, ii] * iw[0] + occ60[:, ii + 1] * iw[1])
                        * alive)
                 rw = _row_power_w(consts, occ, f_lp, f_hp, jnp)
                 frac = jnp.sum(rw) / consts.total_budget
@@ -771,6 +822,11 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
                 c = dict(c, ring=ring, backlog_hp=bh, backlog_lp=bl,
                          imp=imp_buf, peak=jnp.maximum(c["peak"], frac),
                          fsum=c["fsum"] + frac)
+                if cfg.fold:
+                    node_w = _node_w(cfg.fold, rw, jnp)
+                    c = dict(c, node_peak=jnp.maximum(c["node_peak"], node_w),
+                             node_over=c["node_over"] + (
+                                 node_w > consts.node_cap).astype(jnp.int32))
                 ys = ()
                 if cfg.keep_fire:
                     ys += (fire,)
@@ -793,6 +849,10 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
             if cfg.predictive:
                 carry.update(hist_t=jnp.zeros((R, cfg.W)),
                              hist_p=jnp.zeros((R, cfg.W)))
+            if cfg.fold:
+                nodes = _n_nodes(cfg.fold)
+                carry.update(node_peak=jnp.zeros(nodes),
+                             node_over=jnp.zeros(nodes, jnp.int32))
             # the member's fleet folds into power_scale before the loop, so
             # a step multiplies by one loop-invariant factor as it does for
             # a scalar fleet size; power_scale * n_servers * (...) keeps
@@ -802,6 +862,9 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
             final, ys = lax.scan(step_for(occ60, fleet), carry, xs)
             out = dict(nbr=final["nbr"], peak=final["peak"],
                        mean=final["fsum"] / T, imp=final["imp"])
+            if cfg.fold:
+                out.update(node_peak=final["node_peak"],
+                           node_over=final["node_over"])
             i = 0
             if cfg.keep_fire:
                 out["fire"] = ys[i]
@@ -869,7 +932,7 @@ def _geometry_key(model: TickModel) -> tuple:
             max(1, model.window), model.n_slots, model.stride,
             model.oob_ticks, model.brake_ticks, model.escalation_ticks,
             model.predictive, model.n_members, model.occ60.shape[2],
-            float(model.dt))
+            float(model.dt), model.node_shape)
 
 
 def _plan_bucket(models: Sequence[TickModel], *, keep_series: bool,
@@ -912,7 +975,8 @@ def _plan_bucket(models: Sequence[TickModel], *, keep_series: bool,
                   W=max(1, m0.window), S=m0.n_slots, stride=m0.stride,
                   oob_ticks=m0.oob_ticks, brake_ticks=m0.brake_ticks,
                   esc=m0.escalation_ticks, predictive=m0.predictive,
-                  keep_series=keep_series, keep_fire=keep_fire, chunk=chunk)
+                  keep_series=keep_series, keep_fire=keep_fire, chunk=chunk,
+                  fold=m0.node_shape)
     return cfg, mesh, idx
 
 
@@ -921,7 +985,8 @@ def _bucket_operands(models: Sequence[TickModel], idx: np.ndarray) -> tuple:
     the program computes in float64). Per-scenario constants stack on a
     leading ``[M]`` axis, and each member's fleet size rides beside its
     occupancy as ``[M, N]``, padded with the same ``idx``; the tick grid
-    (``t``/``ii``/``iw``) is shared across the bucket by construction
+    (``t``/``ii``/``iw``, the last ``[T, 2]``: each tick's left and right
+    weight) is shared across the bucket by construction
     (geometry-keyed) and passes unbatched so the runner's scenario vmap
     broadcasts it."""
     m0 = models[0]
@@ -934,10 +999,16 @@ def _bucket_operands(models: Sequence[TickModel], idx: np.ndarray) -> tuple:
         **{name: f64([_model_const(m, name) for m in models])
            for name in _CONST_SCALARS},
         n_servers=None,
-        row_budget=f64(np.stack([m.row_budget_w for m in models])))
+        row_budget=f64(np.stack([m.row_budget_w for m in models])),
+        node_cap=(f64(np.stack([m.node_capacity_w for m in models]))
+                  if m0.node_shape else None))
+    # both weights of each tick, (1 - w, w), come from the host: on a TPU
+    # the loop's emulated float64 ``1.0 - w`` kept about float32 precision
+    # for w < 0.5, which put row watts up to 6e-9 from the oracle's
     return (np.stack([m.occ60[idx] for m in models]),
             np.stack([m.servers()[idx] for m in models]), consts,
-            f64(m0.tick_times()), np.asarray(i_idx, dtype=np.int32), f64(i_w),
+            f64(m0.tick_times()), np.asarray(i_idx, dtype=np.int32),
+            f64(np.stack([1.0 - i_w, i_w], axis=1)),
             f64(np.stack([m.alive for m in models])),
             f64(np.stack([m.budget_scale for m in models])),
             np.arange(m0.n_ticks, dtype=np.int32))
@@ -959,6 +1030,9 @@ def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
     Each step is a span of the current recorder (``batched/operands``,
     ``/h2d``, ``/run``, ``/d2h``, ``/unpack``), and the bytes each way are
     its counters ``batched_h2d_bytes_total`` and ``batched_d2h_bytes_total``.
+    A rated tree adds ``batched_node_fold_cells_total`` (members x ticks x
+    nodes folded; ``batched/run`` carries the nodes as its label) and a
+    ``batched/node_stats`` span per model inside ``batched/unpack``.
     Only a recorder that is enabled makes the copy to the device wait, so
     that ``batched/h2d`` times the copy and not its enqueue."""
     import jax
@@ -975,7 +1049,7 @@ def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
             args = jax.tree.map(jax.numpy.asarray, operands)
             if rec.enabled:
                 jax.block_until_ready(args)
-        with rec.span("batched/run"):
+        with rec.span("batched/run", nodes=_n_nodes(cfg.fold)):
             out = _jax_runner(cfg, mesh)(*args)
             jax.block_until_ready(out)
         del args  # the operands' device buffers go before the copy back
@@ -986,6 +1060,9 @@ def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
             a.nbytes for a in jax.tree.leaves(operands))))
         rec.counter("batched_d2h_bytes_total",
                     float(sum(v.nbytes for v in out.values())))
+        if cfg.fold:
+            rec.counter("batched_node_fold_cells_total", float(
+                N * len(models) * cfg.T * _n_nodes(cfg.fold)))
     runs: List[BatchedRun] = []
     with rec.span("batched/unpack"):
         for i, m in enumerate(models):
@@ -1009,6 +1086,12 @@ def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
                 if m.node_matrix is not None:
                     run.node_w = np.einsum("ntr,mr->ntm", run.row_w,
                                            m.node_matrix)
+            if cfg.fold:
+                with rec.span("batched/node_stats"):
+                    run.node_peak_w = np.asarray(sub["node_peak"],
+                                                 dtype=np.float64)
+                    run.node_over_ticks = np.asarray(sub["node_over"],
+                                                     dtype=np.int64)
             runs.append(run)
     return runs
 
@@ -1038,6 +1121,12 @@ def _run_pallas(model: TickModel, keep_series: bool) -> BatchedRun:
             "engine='pallas' runs one fleet size per model; "
             f"{model.base_name!r} stacks the members of several candidate "
             "fleets (use engine='jax' or the numpy oracle)")
+    if model.node_shape:
+        raise ValueError(
+            "engine='pallas' does not fold a rated budget tree; "
+            f"{model.base_name!r} rates its interior nodes "
+            "(HierarchySpec.level_capacity_w): use engine='jax' or the numpy "
+            "oracle")
     if model.predictive:
         raise ValueError(
             "engine='pallas' runs the non-predictive PolcaPolicy tick loop; "
@@ -1177,6 +1266,10 @@ def _to_ensemble_result(model: TickModel, members: List[Scenario],
         brake_counts=np.asarray(run.n_brakes.sum(axis=1)),
         peak_fracs=np.asarray(run.peak_frac),
         mean_fracs=np.asarray(run.mean_frac))
+    if run.node_peak_w is not None:
+        common.update(node_names=model.node_names[model.n_rows:],
+                      node_peak_w=run.node_peak_w,
+                      node_over_ticks=run.node_over_ticks)
     if not member_stats:
         N = run.impacts_hp.shape[0]
         return EnsembleResult(

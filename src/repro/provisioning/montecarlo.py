@@ -161,6 +161,12 @@ class EnsembleResult:
     # 10^5-member result carries no per-member python objects
     member_impacts_hp: Optional[np.ndarray] = field(default=None, repr=False)
     member_impacts_lp: Optional[np.ndarray] = field(default=None, repr=False)
+    # a rated budget tree (HierarchySpec.level_capacity_w), batched engine:
+    # per member and interior node (``node_names``, children before
+    # parents) the peak watts and the count of ticks over the node's rating
+    node_names: Tuple[str, ...] = ()
+    node_peak_w: Optional[np.ndarray] = field(default=None, repr=False)  # [N, nodes]
+    node_over_ticks: Optional[np.ndarray] = field(default=None, repr=False)  # [N, nodes]
 
     @property
     def n_members(self) -> int:

@@ -283,6 +283,20 @@ def test_stacking_refuses_models_that_differ_beyond_members():
         stack_tick_models([a, b])
 
 
+def test_runner_reads_both_interpolation_weights_from_the_host():
+    """Each tick's two occupancy weights, ``1 - w`` and ``w``, are operands
+    computed on the host: the loop subtracts nothing from 1.0, since a TPU's
+    emulated float64 kept ``1.0 - w`` to about float32 precision."""
+    from repro.provisioning import batched
+
+    sc = parity_scenario(duration_s=HALF_HOUR)
+    model, _, _ = lower_ensemble(EnsembleSpec(sc, n_seeds=2, seed0=5))
+    operands = batched._bucket_operands([model], np.arange(2))
+    _, w = batched._interp_weights(model)
+    np.testing.assert_array_equal(operands[5], np.stack([1.0 - w, w], 1))
+    assert np.any((w > 0.0) & (w < 0.5))
+
+
 def test_brakes_actually_fire_and_match():
     """The harness demonstrably covers the brake path: at power_scale=1.30
     the fleet must brake, and the brake-tick sets still match bit-for-bit."""

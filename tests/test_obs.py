@@ -260,7 +260,7 @@ def _batched_nbytes(model, n_dispatches):
     N, R, T, S = model.n_members, model.n_rows, model.n_ticks, model.n_slots
     T60 = model.occ60.shape[2]
     h2d = (8 * N * R * T60 + 8 * N + 8 * len(batched._CONST_SCALARS) + 8 * R
-           + (8 + 4 + 8 + 4) * T + 2 * 8 * T * R)
+           + (8 + 4 + 2 * 8 + 4) * T + 2 * 8 * T * R)
     d2h = (4 * N * R + 8 * N + 8 * N + 8 * N * S * R * 2 + N * T * R
            + 8 * N * T + 8 * N * T * R)
     return n_dispatches * h2d, n_dispatches * d2h
@@ -383,6 +383,53 @@ def test_batched_engine_bit_parity_recorder_on_vs_off():
              p.slo_violation_prob, p.peak_frac_max) for p in plan_on.probes] \
         == [(p.added_servers, p.feasible, p.brake_prob,
              p.slo_violation_prob, p.peak_frac_max) for p in plan_off.probes]
+
+
+def test_rated_tree_fold_span_counter_and_bit_parity(monkeypatch):
+    """A rated tree's dispatch labels ``batched/run`` with the nodes it
+    folds, counts ``batched_node_fold_cells_total`` (members x ticks x
+    nodes) and times the host side of the node results as
+    ``batched/node_stats`` inside ``batched/unpack``; an unrated one is
+    labelled ``nodes=0`` and counts nothing. The recorder leaves every
+    output bit-identical."""
+    import jax
+
+    from repro.experiments.scenario import HierarchySpec
+    from repro.provisioning.batched import lower_ensemble, run_tick_model
+
+    sc = _batched_scenario().with_hierarchy(
+        (2, 2), level_names=("site", "rack"),
+        level_capacity_w=(2.0e4, 1.0e4))
+    model, members, _ = lower_ensemble(EnsembleSpec(sc, n_seeds=3, seed0=5))
+    off = run_tick_model(model, members, engine="jax")
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    rec = MetricsRecorder()
+    with recording(rec):
+        on = run_tick_model(model, members, engine="jax")
+    assert off.node_over_ticks.shape == (3, 3)
+    for name in ("brake_fire", "n_brakes", "peak_frac", "mean_frac",
+                 "total_frac", "row_w", "impacts_hp", "impacts_lp",
+                 "node_w", "node_peak_w", "node_over_ticks"):
+        a, b = getattr(off, name), getattr(on, name)
+        assert a.dtype == b.dtype and np.array_equal(a, b), name
+    snap = rec.snapshot()
+    runs = {labels: s.count for (name, labels), s in snap.spans.items()
+            if name == "batched/run"}
+    assert runs == {(("nodes", "3"),): 1}
+    assert snap.counter_total("batched_node_fold_cells_total") == \
+        3 * model.n_ticks * 3
+    assert ann.children("polca/batched/unpack") == ["polca/batched/node_stats"]
+    bare = sc.with_(hierarchy=HierarchySpec(shape=(2, 2)))
+    model, members, _ = lower_ensemble(EnsembleSpec(bare, n_seeds=3, seed0=5))
+    rec = MetricsRecorder()
+    with recording(rec):
+        run_tick_model(model, members, engine="jax")
+    snap = rec.snapshot()
+    assert {labels for (name, labels) in snap.spans
+            if name == "batched/run"} == {(("nodes", "0"),)}
+    assert "batched/node_stats" not in {name for name, _ in snap.spans}
+    assert snap.counter_total("batched_node_fold_cells_total") == 0
 
 
 @pytest.mark.parametrize("enabled", [False, True])

@@ -60,7 +60,9 @@ FOOTER = """
 heterogeneous per-row budgets (`FleetSpec.row_budget_fracs`), and a
 `tree AxBxC` marks an explicit power-budget hierarchy
 (`HierarchySpec.shape`, root-down fan-outs; `!path` lists derated interior
-nodes). *traffic* names the occupancy generator and its peak busy-server
+nodes; `rated a/b/c MW` the interior levels' ratings root-down,
+`HierarchySpec.level_capacity_w`, `-` for a level rated at its budget).
+*traffic* names the occupancy generator and its peak busy-server
 fraction. *routing* is `router/admission` for fleet scenarios (empty for
 pre-baked per-row traces). *controller* is the power-rebalancing policy
 (`ControllerSpec.kind`, with its rebalance interval and — when not the
@@ -136,6 +138,10 @@ def _fmt_fleet(sc) -> str:
         txt += " tree" + "x".join(str(s) for s in h.shape)
         for path in sorted(h.budget_fracs):
             txt += f" !{path}"
+        if h.level_capacity_w is not None:
+            txt += " rated " + "/".join(
+                "-" if r is None else f"{r / 1e6:g}"
+                for r in h.level_capacity_w) + " MW"
     return txt
 
 

@@ -8,8 +8,8 @@ NaN-sentinel actuation-delay ring. This module is the single home of that
 math, with three consumers:
 
 * ``provisioning.batched._jax_runner`` — the ``lax.scan``/``vmap`` engine
-  calls :func:`polca_latch_step` / :func:`row_power_w` per tick with traced
-  scalars;
+  calls :func:`polca_latch_step` per tick with traced scalars (its power
+  plane reads each row's ``f ** gamma`` from the scenario's clock factors);
 * :func:`polca_tick_loop` — the same step inside one ``pl.pallas_call``:
   grid over member blocks, ``fori_loop`` over ticks, frequency/ring/latch
   state carried in-kernel, per-tick loads/stores against the block refs.
@@ -76,9 +76,9 @@ class PolcaLatches(NamedTuple):
 
 
 def row_power_w(c, occ, f_lp, f_hp):
-    """Per-row watts at occupancy + frequency state — the identical
-    expression ``provisioning.batched._row_power_w`` evaluates (kept in
-    lockstep by the differential parity gates)."""
+    """Per-row watts at occupancy + frequency state — the expression
+    ``provisioning.batched._row_power_w`` evaluates on ``f ** gamma`` (kept
+    in lockstep by the differential parity gates)."""
     busy = c.k_lp_w * f_lp ** c.gamma + c.k_hp_w * f_hp ** c.gamma
     return c.power_scale * c.n_servers * (c.p0_srv_w + occ * busy)
 

@@ -473,19 +473,47 @@ def lower_ensemble(spec: EnsembleSpec, *, budget_w: Optional[float] = None
 # shared tick math (both backends call these with their own array module)
 # ---------------------------------------------------------------------------
 
-def _row_power_w(model: TickModel, occ, f_lp, f_hp, xp):
-    """Per-row watts at occupancy + frequency state (the closed-form fluid
-    power plane; identical expression on both backends)."""
-    busy = (model.k_lp_w * f_lp ** model.gamma
-            + model.k_hp_w * f_hp ** model.gamma)
+def _row_power_w(model: TickModel, occ, g_lp, g_hp):
+    """Per-row watts at occupancy + clock factors ``g = f ** gamma`` (the
+    closed-form fluid power plane; identical expression on both
+    backends)."""
+    busy = model.k_lp_w * g_lp + model.k_hp_w * g_hp
     return (model.power_scale * model.n_servers
             * (model.p0_srv_w + occ * busy))
 
 
-def _lp_power_w(model: TickModel, occ, f_lp, xp):
+def _lp_power_w(model: TickModel, occ, g_lp):
     return (model.power_scale * model.n_servers
-            * (model.lp_share * model.p0_srv_w
-               + occ * model.k_lp_w * f_lp ** model.gamma))
+            * (model.lp_share * model.p0_srv_w + occ * model.k_lp_w * g_lp))
+
+
+# the clocks a row runs at besides the uncapped 1.0: the latch step's
+# commands and the brake. LP holds 1.0, lp_t1, lp_t2 or the brake clock; HP
+# 1.0, hp_t2 or the brake clock
+_CLOCKS = ("lp_t1", "lp_t2", "hp_t2", "brake_freq")
+_LP_CLOCKS = ("lp_t1", "lp_t2", "brake_freq")
+_HP_CLOCKS = ("hp_t2", "brake_freq")
+# programs of at least this many rows read f ** gamma from the clock table;
+# narrower ones compute the pow. On a v5e the table made the [256, 56] site
+# scan 2.5x faster, but one-row scans slower (2% at 512 members, 8% at
+# 200): it removes 28 of 79 kernels a step, yet leaves two that read 18-19
+# scalar operands and run about 4x longer, a cost fixed per kernel while
+# the pow's grows with the rows (measured: 1 and 56 rows)
+_CLOCK_TABLE_MIN_ROWS = 2
+
+
+def _clock_factors(c, f_lp, f_hp, xp):
+    """``(f_lp ** gamma, f_hp ** gamma)`` read from the scenario's table of
+    clock factors (the ``_Consts`` fields ``gf_<clock>``, ``gf_one`` for
+    1.0) by exact equality, for clocks the step can hold. A select chain:
+    the device computes no ``pow``, which a TPU emulates in float64 at
+    thousands of instructions."""
+    def pick(f, clocks):
+        g = c.gf_one
+        for name in clocks:
+            g = xp.where(f == getattr(c, name), getattr(c, "gf_" + name), g)
+        return g
+    return pick(f_lp, _LP_CLOCKS), pick(f_hp, _HP_CLOCKS)
 
 
 def _slo_step(model: TickModel, occ, f_lp, f_hp, backlog_hp, backlog_lp, xp):
@@ -574,7 +602,8 @@ def _run_oracle(model: TickModel, members: List[Scenario],
             ring[:, slot, :] = np.nan
             occ = (occ60[:, i_idx[k]] * (1.0 - i_w[k])
                    + occ60[:, i_idx[k] + 1] * i_w[k]) * model.alive[k]
-            rw = _row_power_w(pm, occ, f_lp, f_hp, np)
+            g_lp = f_lp ** model.gamma
+            rw = _row_power_w(pm, occ, g_lp, f_hp ** model.gamma)
             frac = float(rw.sum()) / total_budget
             frac_peak = max(frac_peak, frac)
             frac_sum += frac
@@ -587,7 +616,7 @@ def _run_oracle(model: TickModel, members: List[Scenario],
                 node_over[m] += nw > model.node_capacity_w
             tick_budget = model.row_budget_w * model.budget_scale[k]
             p = rw / tick_budget
-            lp_frac = _lp_power_w(pm, occ, f_lp, np) / tick_budget
+            lp_frac = _lp_power_w(pm, occ, g_lp) / tick_budget
             for r in range(R):
                 pol = policies[r]
                 before = pol.n_brakes
@@ -655,6 +684,9 @@ class _JaxCfg(NamedTuple):
     # a rated tree's root-down fan-outs (TickModel.node_shape), folded in
     # the loop; () compiles the program without the fold
     fold: Tuple[int, ...] = ()
+    # f ** gamma from the clock table (_clock_factors), else the pow; set
+    # from R (_CLOCK_TABLE_MIN_ROWS)
+    clock_table: bool = True
 
 
 class _Consts(NamedTuple):
@@ -680,6 +712,12 @@ class _Consts(NamedTuple):
     k_hp_w: object
     lp_share: object
     gamma: object
+    # f ** gamma of each clock the step can hold (_clock_factors)
+    gf_one: object
+    gf_lp_t1: object
+    gf_lp_t2: object
+    gf_hp_t2: object
+    gf_brake_freq: object
     n_servers: object
     power_scale: object
     dt: object
@@ -775,11 +813,15 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
                     c["ring"], jnp.full((R, 2), jnp.nan), slot, axis=1)
                 occ = ((occ60[:, ii] * iw[0] + occ60[:, ii + 1] * iw[1])
                        * alive)
-                rw = _row_power_w(consts, occ, f_lp, f_hp, jnp)
+                if cfg.clock_table:
+                    g_lp, g_hp = _clock_factors(consts, f_lp, f_hp, jnp)
+                else:
+                    g_lp, g_hp = f_lp ** consts.gamma, f_hp ** consts.gamma
+                rw = _row_power_w(consts, occ, g_lp, g_hp)
                 frac = jnp.sum(rw) / consts.total_budget
                 tick_budget = consts.row_budget * bscale
                 p_raw = rw / tick_budget
-                lp_frac = _lp_power_w(consts, occ, f_lp, jnp) / tick_budget
+                lp_frac = _lp_power_w(consts, occ, g_lp) / tick_budget
                 c = dict(c, f_lp=f_lp, f_hp=f_hp, ring=ring, k=k)
                 if cfg.predictive:
                     c, p_obs = predict(c, t, p_raw, consts)
@@ -976,7 +1018,8 @@ def _plan_bucket(models: Sequence[TickModel], *, keep_series: bool,
                   oob_ticks=m0.oob_ticks, brake_ticks=m0.brake_ticks,
                   esc=m0.escalation_ticks, predictive=m0.predictive,
                   keep_series=keep_series, keep_fire=keep_fire, chunk=chunk,
-                  fold=m0.node_shape)
+                  fold=m0.node_shape,
+                  clock_table=m0.n_rows >= _CLOCK_TABLE_MIN_ROWS)
     return cfg, mesh, idx
 
 
@@ -995,10 +1038,15 @@ def _bucket_operands(models: Sequence[TickModel], idx: np.ndarray) -> tuple:
         return np.asarray(vals, dtype=np.float64)
 
     i_idx, i_w = _interp_weights(m0)
+    scalars = {name: f64([_model_const(m, name) for m in models])
+               for name in _CONST_SCALARS}
+    # each clock's f ** gamma by numpy's array pow, the one the oracle
+    # evaluates (with AVX-512 it may differ by an ulp from the scalar pow)
+    clocks = dict(one=np.ones(len(models)),
+                  **{name: scalars[name] for name in _CLOCKS})
     consts = _Consts(
-        **{name: f64([_model_const(m, name) for m in models])
-           for name in _CONST_SCALARS},
-        n_servers=None,
+        **scalars, n_servers=None,
+        **{"gf_" + name: f ** scalars["gamma"] for name, f in clocks.items()},
         row_budget=f64(np.stack([m.row_budget_w for m in models])),
         node_cap=(f64(np.stack([m.node_capacity_w for m in models]))
                   if m0.node_shape else None))
@@ -1033,6 +1081,10 @@ def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
     A rated tree adds ``batched_node_fold_cells_total`` (members x ticks x
     nodes folded; ``batched/run`` carries the nodes as its label) and a
     ``batched/node_stats`` span per model inside ``batched/unpack``.
+    ``batched_clock_table_cells_total`` counts the members x ticks x rows
+    whose ``f ** gamma`` the loop read from the clock table
+    (:func:`_clock_factors`, 0 where it computed the pow); ``batched/run``
+    carries the path as its label ``power``, ``table`` or ``pow``.
     Only a recorder that is enabled makes the copy to the device wait, so
     that ``batched/h2d`` times the copy and not its enqueue."""
     import jax
@@ -1049,7 +1101,8 @@ def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
             args = jax.tree.map(jax.numpy.asarray, operands)
             if rec.enabled:
                 jax.block_until_ready(args)
-        with rec.span("batched/run", nodes=_n_nodes(cfg.fold)):
+        with rec.span("batched/run", nodes=_n_nodes(cfg.fold),
+                      power="table" if cfg.clock_table else "pow"):
             out = _jax_runner(cfg, mesh)(*args)
             jax.block_until_ready(out)
         del args  # the operands' device buffers go before the copy back
@@ -1063,6 +1116,8 @@ def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
         if cfg.fold:
             rec.counter("batched_node_fold_cells_total", float(
                 N * len(models) * cfg.T * _n_nodes(cfg.fold)))
+        rec.counter("batched_clock_table_cells_total",
+                    float(N * len(models) * cfg.T * cfg.R * cfg.clock_table))
     runs: List[BatchedRun] = []
     with rec.span("batched/unpack"):
         for i, m in enumerate(models):

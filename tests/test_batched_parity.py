@@ -308,6 +308,86 @@ def test_brakes_actually_fire_and_match():
     assert_engine_parity(oracle, jaxed)
 
 
+# ---------------------------------------------------------------------------
+# clock factors: each row's f ** gamma read from the scenario's table
+# ---------------------------------------------------------------------------
+
+# the policy's default clocks, clocks of its own, and two that coincide
+CLOCK_POLICIES = {
+    "default": {},
+    "own": dict(lp_freq_t1=0.93, lp_freq_t2=0.81, hp_freq_t2=0.88,
+                brake_freq=0.3),
+    "lp_t2_is_brake": dict(lp_freq_t2=0.75, brake_freq=0.75),
+}
+
+
+@pytest.mark.parametrize("params", CLOCK_POLICIES.values(),
+                         ids=CLOCK_POLICIES.keys())
+def test_step_clock_factors_equal_numpy_pow_bit_for_bit(params):
+    """For every clock a row can hold (1.0, lp_t1, lp_t2, hp_t2 and the
+    brake clock), the step's factor equals numpy's ``f ** gamma`` — the
+    oracle's — bit for bit, also where two clocks coincide."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.provisioning import batched
+
+    sc = parity_scenario(duration_s=HALF_HOUR).with_policy("polca", **params)
+    model, _, _ = lower_ensemble(EnsembleSpec(sc, n_seeds=2))
+    consts = jax.tree.map(lambda a: a[0],
+                          batched._bucket_operands([model], np.arange(2))[2])
+    clocks = np.array([1.0, model.lp_freq_t1, model.lp_freq_t2,
+                       model.hp_freq_t2, model.brake_freq])
+    if params:
+        assert model.lp_freq_t2 == params["lp_freq_t2"]
+    with jax.enable_x64(True):
+        g_lp, g_hp = jax.jit(lambda c, f: batched._clock_factors(
+            c, f, f, jnp))(consts, jnp.asarray(clocks))
+    want = clocks ** model.gamma
+    lp, hp = [0, 1, 2, 4], [0, 3, 4]  # the clocks each priority can hold
+    np.testing.assert_array_equal(np.asarray(g_lp)[lp], want[lp])
+    np.testing.assert_array_equal(np.asarray(g_hp)[hp], want[hp])
+
+
+def test_predictive_brakes_fire_and_match():
+    """The brake-covering scenario of
+    :func:`test_brakes_actually_fire_and_match` under the predictive
+    policy: its clocks come from the same table, and brake sets and power
+    still match the oracle."""
+    sc = parity_scenario(occ_peak=0.99, power_scale=1.30,
+                         duration_s=HALF_HOUR, policy="polca-predictive")
+    _, oracle, jaxed = run_both_engines(sc, n_seeds=2)
+    assert oracle.n_brakes.sum() > 0, "scenario failed to exercise brakes"
+    assert_engine_parity(oracle, jaxed)
+
+
+@pytest.mark.parametrize("site", [False, True], ids=["one_row", "site56"])
+def test_scan_program_computes_power_only_at_one_row(site):
+    """Programs of two rows or more read their clock factors from the
+    table and lower no ``power`` op (the 56-row site's); a one-row program
+    computes ``f ** gamma`` (the row40 cells')."""
+    import jax
+
+    from repro.experiments import get_scenario
+    from repro.provisioning import batched
+
+    assert batched._CLOCK_TABLE_MIN_ROWS == 2
+    sc = (get_scenario("site56").with_(duration_s=HALF_HOUR) if site
+          else parity_scenario(n_rows=1, duration_s=HALF_HOUR))
+    model, _, _ = lower_ensemble(EnsembleSpec(sc, n_seeds=2))
+    assert model.n_rows == (56 if site else 1)
+    assert bool(model.node_shape) == site
+    cfg, mesh, idx = batched._plan_bucket(
+        [model], keep_series=False, keep_fire=False, member_chunk=None,
+        mesh=None)
+    assert cfg.clock_table == site
+    with jax.enable_x64(True):
+        text = batched._jax_runner(cfg, mesh).lower(
+            *batched._bucket_operands([model], idx)).as_text()
+    assert "stablehlo.while" in text
+    assert ("stablehlo.power" in text) != site
+
+
 def test_quiet_scenario_is_quiet_on_both_engines():
     """Low occupancy: no brakes, no caps biting, ~zero SLO impact — and the
     engines agree exactly."""

@@ -72,6 +72,29 @@ def test_run_ensemble_grid_jax_dispatch():
         _assert_results_identical(out[b.name], single)
 
 
+def test_grid_scenarios_with_their_own_clocks_match_their_own_runs():
+    """Two policies whose clocks differ share one geometry bucket: each
+    scenario's clock factors ride the scenario vmap as ``[M]`` leaves, so
+    each result equals the scenario's own run bit for bit."""
+    from repro.provisioning.batched import _geometry_key
+
+    hot = parity_scenario(occ_peak=0.99, power_scale=1.30,
+                          duration_s=1800.0)
+    specs = [EnsembleSpec(hot, n_seeds=3),
+             EnsembleSpec(hot.with_policy(
+                 "polca", lp_freq_t1=0.93, lp_freq_t2=0.81, hp_freq_t2=0.88,
+                 brake_freq=0.3).with_(name="parity-own-clocks"),
+                 n_seeds=3)]
+    models = [lower_ensemble(s)[0] for s in specs]
+    assert _geometry_key(models[0]) == _geometry_key(models[1])
+    assert models[0].lp_freq_t2 != models[1].lp_freq_t2
+    grid = run_batched_grid(specs, engine="jax")
+    for g, s in zip(grid, specs):
+        _assert_results_identical(g, run_batched_ensemble(s, engine="jax"))
+    assert grid[0].brake_counts.sum() > 0
+    assert not np.array_equal(grid[0].power_frac, grid[1].power_frac)
+
+
 @pytest.mark.parametrize("chunk", [3, 5, 12])
 def test_member_chunk_invariance(chunk):
     """Chunked lax.scan over member blocks (including a non-dividing chunk,

@@ -259,7 +259,8 @@ def _batched_nbytes(model, n_dispatches):
 
     N, R, T, S = model.n_members, model.n_rows, model.n_ticks, model.n_slots
     T60 = model.occ60.shape[2]
-    h2d = (8 * N * R * T60 + 8 * N + 8 * len(batched._CONST_SCALARS) + 8 * R
+    h2d = (8 * N * R * T60 + 8 * N + 8 * len(batched._CONST_SCALARS)
+           + 8 * (1 + len(batched._CLOCKS)) + 8 * R
            + (8 + 4 + 2 * 8 + 4) * T + 2 * 8 * T * R)
     d2h = (4 * N * R + 8 * N + 8 * N + 8 * N * S * R * 2 + N * T * R
            + 8 * N * T + 8 * N * T * R)
@@ -416,7 +417,7 @@ def test_rated_tree_fold_span_counter_and_bit_parity(monkeypatch):
     snap = rec.snapshot()
     runs = {labels: s.count for (name, labels), s in snap.spans.items()
             if name == "batched/run"}
-    assert runs == {(("nodes", "3"),): 1}
+    assert runs == {(("nodes", "3"), ("power", "table")): 1}
     assert snap.counter_total("batched_node_fold_cells_total") == \
         3 * model.n_ticks * 3
     assert ann.children("polca/batched/unpack") == ["polca/batched/node_stats"]
@@ -427,9 +428,48 @@ def test_rated_tree_fold_span_counter_and_bit_parity(monkeypatch):
         run_tick_model(model, members, engine="jax")
     snap = rec.snapshot()
     assert {labels for (name, labels) in snap.spans
-            if name == "batched/run"} == {(("nodes", "0"),)}
+            if name == "batched/run"} == {(("nodes", "0"),
+                                           ("power", "table"))}
     assert "batched/node_stats" not in {name for name, _ in snap.spans}
     assert snap.counter_total("batched_node_fold_cells_total") == 0
+
+
+def test_clock_table_counter_and_power_label():
+    """A program of two rows or more reads each row's ``f ** gamma`` from
+    the clock table: ``batched_clock_table_cells_total`` counts its members
+    x ticks x rows (a grid's scenarios each add theirs) and ``batched/run``
+    carries ``power="table"``. A one-row program computes the pow: it
+    counts nothing and carries ``power="pow"``."""
+    from repro.provisioning.batched import (
+        lower_ensemble,
+        run_batched_grid,
+        run_tick_model,
+    )
+
+    sc = _batched_scenario()
+    model, members, _ = lower_ensemble(EnsembleSpec(sc, n_seeds=3, seed0=5))
+    assert model.n_rows == 2
+    rec = MetricsRecorder()
+    with recording(rec):
+        run_tick_model(model, members, engine="jax")
+        run_batched_grid([EnsembleSpec(sc, n_seeds=3, seed0=5),
+                          EnsembleSpec(sc.with_(power_scale=1.1), n_seeds=3,
+                                       seed0=9)], engine="jax")
+    snap = rec.snapshot()
+    cells = 3 * model.n_ticks * model.n_rows
+    assert snap.counter_total("batched_clock_table_cells_total") == 3 * cells
+    runs = {labels: s.count for (name, labels), s in snap.spans.items()
+            if name == "batched/run"}
+    assert runs == {(("nodes", "0"), ("power", "table")): 2}
+    one = sc.with_fleet(n_rows=1, rows_per_rack=1)
+    model, members, _ = lower_ensemble(EnsembleSpec(one, n_seeds=3, seed0=5))
+    rec = MetricsRecorder()
+    with recording(rec):
+        run_tick_model(model, members, engine="jax")
+    snap = rec.snapshot()
+    assert snap.counter_total("batched_clock_table_cells_total") == 0
+    assert {labels for (name, labels) in snap.spans
+            if name == "batched/run"} == {(("nodes", "0"), ("power", "pow"))}
 
 
 @pytest.mark.parametrize("enabled", [False, True])

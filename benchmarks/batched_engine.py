@@ -1,12 +1,11 @@
-"""Batched ensemble engine: oracle/kernel parity, grid lowering, dense-tail
+"""Batched ensemble engine: oracle parity, grid lowering, dense-tail
 throughput (DESIGN.md §15-16).
 
 Validates the subsystem's claims:
-  * the jax jit/vmap/`lax.scan` device program AND the Pallas tick kernel
-    backend reproduce the numpy tick oracle exactly — brake-tick sets
-    bit-identical, power series within 1e-6 relative (the differential
-    contract tier-1 drills property-style in tests/test_batched_parity.py
-    and tests/test_grid_engine.py);
+  * the jax jit/vmap/`lax.scan` device program reproduces the numpy tick
+    oracle exactly — brake-tick sets bit-identical, power series within
+    1e-6 relative (the differential contract tier-1 drills property-style
+    in tests/test_batched_parity.py and tests/test_grid_engine.py);
   * a grid of >= 4 scenarios x 10^3 members runs as ONE scenario-vmapped,
     once-traced device program, bit-identical to the per-scenario loop,
     with per-member throughput >= the flat-vmap engine it grew out of
@@ -87,18 +86,6 @@ def run(quick: bool = False) -> Bench:
           f"brake_sets_identical={brake_ok} ({n_brakes} brakes exercised) "
           f"power_rel_err={rel:.1e} (bound 1e-6)",
           us, brake_ok and rel <= 1e-6 and n_brakes > 0)
-
-    # ---- Pallas tick kernel vs the same oracle ----------------------------
-    t0 = time.perf_counter()
-    pal = run_tick_model(model, members, engine="pallas")
-    us = (time.perf_counter() - t0) * 1e6
-    pal_brake_ok = bool(np.array_equal(oracle.brake_fire, pal.brake_fire))
-    pal_rel = float(np.max(np.abs(pal.total_frac - oracle.total_frac)
-                           / np.maximum(np.abs(oracle.total_frac), 1e-12)))
-    b.add("batched/pallas_kernel_parity",
-          f"brake_sets_identical={pal_brake_ok} power_rel_err={pal_rel:.1e} "
-          f"(bound 1e-6; same {n_brakes}-brake workload as the scan engine)",
-          us, pal_brake_ok and pal_rel <= 1e-6)
 
     # ---- grid: M scenarios as ONE scenario-vmapped program ----------------
     # Engine-layer measurements on pre-lowered models, so the measured

@@ -1,3 +1,4 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# The serve path's Pallas attention kernels: flash attention for prefill
+# (flash_attention.py) and flash decoding (decode_attention.py), their jitted
+# wrappers (ops.py) and plain-jnp references (ref.py). The POLCA tick step is
+# not a kernel: it lives in provisioning/batched.py.

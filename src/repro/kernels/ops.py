@@ -9,11 +9,9 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
-from repro.kernels import tick as _tick
 
 
 def _auto_interpret(interpret):
@@ -40,20 +38,3 @@ def decode_attention(q, k, v, valid_len, *, softcap=0.0,
         q, k, v, valid_len, softcap=softcap, block_k=block_k,
         interpret=_auto_interpret(interpret))
 
-
-@partial(jax.jit, static_argnames=("consts", "oob_ticks", "brake_ticks",
-                                   "ring_depth", "esc", "block_members",
-                                   "block_ticks", "interpret"))
-def polca_tick(occ, bscale, row_budget, *, consts, oob_ticks, brake_ticks,
-               ring_depth, esc, block_members=_tick.DEFAULT_BLOCK_MEMBERS,
-               block_ticks=_tick.DEFAULT_BLOCK_TICKS, interpret=None):
-    """Non-predictive POLCA tick loop (power fold + latch/ring update) as a
-    Pallas kernel. ``consts`` is a hashable :class:`~repro.kernels.tick.
-    TickConsts` — per-scenario scalars are compile-time here (the scan
-    engine in ``provisioning.batched`` is the probe-sweep path; this kernel
-    recompiles per scenario by design)."""
-    return _tick.polca_tick_loop(
-        occ, bscale, row_budget, consts, oob_ticks=oob_ticks,
-        brake_ticks=brake_ticks, ring_depth=ring_depth, esc=esc,
-        block_members=block_members, block_ticks=block_ticks,
-        interpret=_auto_interpret(interpret))

@@ -87,37 +87,3 @@ def rmsnorm_reference(x, w, eps=1e-6):
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     return (xf * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(x.dtype)
 
-
-def polca_tick_reference(occ, bscale, row_budget, consts, *, oob_ticks,
-                         brake_ticks, ring_depth, esc):
-    """Plain ``lax.scan`` form of the POLCA tick loop — the shell oracle for
-    :func:`repro.kernels.tick.polca_tick_loop`.
-
-    Shares ``tick._tick_body`` with the kernel on purpose: this reference
-    isolates the Pallas plumbing (member blocking, ring/scratch indexing,
-    per-tick loads/stores, padding) rather than re-deriving the state
-    machine. Semantic ground truth for the step itself is the numpy tick
-    oracle driving the *real* policy objects (``tests/test_batched_parity``
-    runs ``engine="pallas"`` through that differential harness).
-
-    occ: [N,T,R] effective occupancy; bscale: [T,R]; row_budget: [R].
-    """
-    from repro.kernels import tick as _tick
-
-    N, T, R = occ.shape
-    init = _tick._tick_init(N, R, ring_depth, occ.dtype)
-
-    def step(carry, x):
-        k, occ_k, bs_k = x
-        carry, rw, fire = _tick._tick_body(
-            k, carry, occ_k, bs_k, row_budget, consts,
-            oob_ticks=oob_ticks, brake_ticks=brake_ticks,
-            ring_depth=ring_depth, esc=esc)
-        return carry, (rw, fire, carry[0], carry[1])
-
-    xs = (jnp.arange(T, dtype=jnp.int32), jnp.moveaxis(occ, 1, 0), bscale)
-    _, (rw, fire, f_lp, f_hp) = jax.lax.scan(step, init, xs)
-    return dict(row_w=jnp.moveaxis(rw, 0, 1), fire=jnp.moveaxis(fire, 0, 1),
-                f_lp=jnp.moveaxis(f_lp, 0, 1),
-                f_hp=jnp.moveaxis(f_hp, 0, 1),
-                n_brakes=fire.sum(axis=0, dtype=jnp.int32))

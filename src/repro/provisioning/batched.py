@@ -20,21 +20,18 @@ program (DESIGN.md §15):
   every tick, and each interior node keeps its peak watts and its count of
   ticks over its rating (:func:`_node_w`).
 
-* **Three backends, one contract** — ``engine="jax"`` runs the tick advance
+* **Two backends, one contract** — ``engine="jax"`` runs the tick advance
   as a ``lax.scan`` over time ``vmap``-ed over members, with the
   :class:`~repro.core.policy.PolcaPolicy` /
   :class:`~repro.core.policy.PredictivePolcaPolicy` observe step (windowed
   least-squares slope over the 40 s OOB horizon) carried in scan state as a
-  vectorized boolean state machine; the latch math lives in
-  :func:`repro.kernels.tick.polca_latch_step`, shared with the Pallas
-  backend. ``engine="pallas"`` runs the non-predictive tick inner loop
-  (power fold + latch/ring update) as the :func:`repro.kernels.ops.
-  polca_tick` kernel, interpret-mode on CPU. ``engine="numpy"`` is the
-  differential **oracle**: the identical tick/ring contract driven by the
-  *real* policy objects through :class:`~repro.core.telemetry.Telemetry`,
-  one instance per (member, row) — so the vectorized state machine is
-  checked against the genuine Algorithm-1 implementation, not a
-  reimplementation of itself (``tests/test_batched_parity.py``).
+  vectorized boolean state machine (:func:`polca_latch_step`).
+  ``engine="numpy"`` is the differential **oracle**: the identical
+  tick/ring contract driven by the *real* policy objects through
+  :class:`~repro.core.telemetry.Telemetry`, one instance per (member, row)
+  — so the vectorized state machine is checked against the genuine
+  Algorithm-1 implementation, not a reimplementation of itself
+  (``tests/test_batched_parity.py``).
 
 * **Grids, shards, chunks (DESIGN.md §16)** — per-scenario scalars are
   *traced* operands (:class:`_Consts`), not compile-time constants, so one
@@ -516,6 +513,71 @@ def _clock_factors(c, f_lp, f_hp, xp):
     return pick(f_lp, _LP_CLOCKS), pick(f_hp, _HP_CLOCKS)
 
 
+class PolcaLatches(NamedTuple):
+    """The boolean cap/brake state machine of one policy instance,
+    vectorized over arbitrary leading shape (rows, or members x rows)."""
+
+    t1c: object  # T1 cap active
+    t2c: object  # T2 cap active
+    hpc: object  # HP cap active (escalated)
+    brk: object  # braking right now
+    t2s: object  # escalation tick counter (int32)
+
+
+def polca_latch_step(latches: PolcaLatches, p_obs, p_raw, lp_frac, c, xp, *,
+                     esc: int, predictive: bool):
+    """One vectorized tick of ``PolcaPolicy.observe`` over any batch shape.
+
+    Mirrors ``core.policy`` line for line: the overload path sets every cap
+    flag and skips releases; cap/escalation branches run only out of
+    overload; releases read the *post-cap* flags, and the T1 release
+    additionally requires T2 to have just released or been clear.
+    ``predictive`` adds the informed-escalation shortcut of
+    ``PredictivePolcaPolicy`` (p_obs is then the extrapolated power).
+
+    Returns ``(latches', fire, lp_cmd, hp_cmd)`` — ``fire`` marks brake
+    firings; the command planes are NaN where no command is issued, in the
+    policy's cmd-list order (later overwrites earlier, the DES
+    same-due-time rule).
+    """
+    t1c, t2c, hpc, brk, t2s = latches
+    over = p_obs > 1.0
+    fire = over & ~brk
+    rel_brake = ~over & brk
+    if predictive:
+        informed = (t2c & ~hpc & (p_raw > c.t2)
+                    & (lp_frac < p_raw - c.t2))
+        t2s = xp.where(informed, esc, t2s)
+    hi2 = p_obs > c.t2
+    cap_t2 = ~over & hi2 & ~t2c
+    esc_tick = ~over & hi2 & t2c & ~hpc
+    t2s = xp.where(cap_t2, 0, xp.where(esc_tick, t2s + 1, t2s))
+    cap_hp = esc_tick & (t2s >= esc)
+    cap_t1 = ~over & ~hi2 & (p_obs > c.t1) & ~t1c
+    t2c_mid = t2c | over | cap_t2
+    t1c_mid = t1c | over | cap_t2 | cap_t1
+    hpc_mid = hpc | over | cap_hp
+    rel_t2 = ~over & t2c_mid & (p_obs < c.t2 - c.t2_buf)
+    t2c = t2c_mid & ~rel_t2
+    hpc = hpc_mid & ~rel_t2
+    rel_t1 = (~over & t1c_mid & ~t2c
+              & (p_obs < c.t1 - c.t1_buf))
+    t1c = t1c_mid & ~rel_t1
+    nanv = xp.full(p_obs.shape, xp.nan, dtype=p_obs.dtype)
+    lp_cmd = nanv
+    hp_cmd = nanv
+    lp_cmd = xp.where(rel_brake, c.lp_t2, lp_cmd)
+    hp_cmd = xp.where(rel_brake, c.hp_t2, hp_cmd)
+    lp_cmd = xp.where(cap_t2, c.lp_t2, lp_cmd)
+    hp_cmd = xp.where(cap_hp, c.hp_t2, hp_cmd)
+    lp_cmd = xp.where(cap_t1, c.lp_t1, lp_cmd)
+    lp_cmd = xp.where(rel_t2, c.lp_t1, lp_cmd)
+    hp_cmd = xp.where(rel_t2, 1.0, hp_cmd)
+    lp_cmd = xp.where(rel_t1, 1.0, lp_cmd)
+    return (PolcaLatches(t1c=t1c, t2c=t2c, hpc=hpc, brk=over, t2s=t2s),
+            fire, lp_cmd, hp_cmd)
+
+
 def _slo_step(model: TickModel, occ, f_lp, f_hp, backlog_hp, backlog_lp, xp):
     """One tick of the per-priority fluid SLO proxy: slowdown from the DVFS
     perf model (``a/f + (1-a)``) plus a queue-delay backlog integrator —
@@ -695,9 +757,8 @@ class _Consts(NamedTuple):
     maps over the leading axis of every leaf); ``row_budget`` is ``[R]`` /
     ``[M, R]``. ``n_servers`` is ``None`` in the operands: the fleet size
     is per member (the runner's ``[M, N]`` operand) and each member's
-    program fills it in. Field names match
-    :class:`repro.kernels.tick.TickConsts` so the shared step math reads
-    either."""
+    program fills it in. The shared step math (:func:`_row_power_w`,
+    :func:`polca_latch_step`, ...) reads its fields by name."""
 
     t1: object
     t2: object
@@ -766,8 +827,6 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
     import jax.numpy as jnp
     from jax import lax
 
-    from repro.kernels.tick import PolcaLatches, polca_latch_step
-
     def predict(c, t, p, consts):
         """PredictivePolcaPolicy._predict: windowed least-squares slope
         extrapolated horizon_s ahead, clamped below 1.0 unless the measured
@@ -830,7 +889,7 @@ def _jax_runner(cfg: _JaxCfg, mesh=None):
                 lat = PolcaLatches(t1c=c["t1c"], t2c=c["t2c"], hpc=c["hpc"],
                                    brk=c["brk"], t2s=c["t2s"])
                 lat, fire, lp_cmd, hp_cmd = polca_latch_step(
-                    lat, p_obs, p_raw, lp_frac, consts,
+                    lat, p_obs, p_raw, lp_frac, consts, jnp,
                     esc=cfg.esc, predictive=cfg.predictive)
                 c = dict(c, t1c=lat.t1c, t2c=lat.t2c, hpc=lat.hpc,
                          brk=lat.brk, t2s=lat.t2s,
@@ -1151,103 +1210,6 @@ def _run_jax_models(models: Sequence[TickModel], *, keep_series: bool,
     return runs
 
 
-def _run_jax(model: TickModel, keep_series: bool, *, keep_fire: bool = True,
-             member_chunk: Optional[int] = None, mesh=None) -> BatchedRun:
-    return _run_jax_models([model], keep_series=keep_series,
-                           keep_fire=keep_fire, member_chunk=member_chunk,
-                           mesh=mesh)[0]
-
-
-# ---------------------------------------------------------------------------
-# pallas engine: the tick inner loop as a kernel (repro.kernels.tick)
-# ---------------------------------------------------------------------------
-
-def _run_pallas(model: TickModel, keep_series: bool) -> BatchedRun:
-    """Tick loop on the Pallas kernel backend (``repro.kernels.tick``).
-
-    The kernel owns what dominates the scan body — the power fold, the
-    latch update, and the actuation ring — per member block; occupancy
-    interpolation and the SLO fluid proxy run as numpy pre/post-passes
-    using the *same expressions* as the oracle (elementwise, so those
-    planes are bit-identical by construction and the differential gate
-    pins the kernel's brake sets / power series)."""
-    if model.member_servers is not None:
-        raise ValueError(
-            "engine='pallas' runs one fleet size per model; "
-            f"{model.base_name!r} stacks the members of several candidate "
-            "fleets (use engine='jax' or the numpy oracle)")
-    if model.node_shape:
-        raise ValueError(
-            "engine='pallas' does not fold a rated budget tree; "
-            f"{model.base_name!r} rates its interior nodes "
-            "(HierarchySpec.level_capacity_w): use engine='jax' or the numpy "
-            "oracle")
-    if model.predictive:
-        raise ValueError(
-            "engine='pallas' runs the non-predictive PolcaPolicy tick loop; "
-            f"{model.base_name!r} lowered a predictive policy (use "
-            "engine='jax', which carries the slope window in scan state)")
-    import jax
-    import jax.numpy as jnp
-
-    if jax.default_backend() == "tpu":
-        raise NotImplementedError(
-            "engine='pallas' runs the tick kernel in float64 (the oracle "
-            "contract), and Mosaic has no float64 on TPU; use engine='jax'")
-
-    from repro.kernels import ops as kops
-    from repro.kernels.tick import TickConsts
-
-    N, R, T = model.n_members, model.n_rows, model.n_ticks
-    i_idx, i_w = _interp_weights(model)
-    # effective per-tick occupancy — the oracle's expression, vectorized
-    occ = ((model.occ60[:, :, i_idx] * (1.0 - i_w)
-            + model.occ60[:, :, i_idx + 1] * i_w)
-           * model.alive.T[None])  # [N, R, T]
-    occ_ntr = np.ascontiguousarray(occ.transpose(0, 2, 1))
-    consts = TickConsts(
-        t1=model.t1, t2=model.t2, t1_buf=model.t1_buffer,
-        t2_buf=model.t2_buffer, lp_t1=model.lp_freq_t1,
-        lp_t2=model.lp_freq_t2, hp_t2=model.hp_freq_t2,
-        brake_freq=model.brake_freq, p0_srv_w=model.p0_srv_w,
-        k_lp_w=model.k_lp_w, k_hp_w=model.k_hp_w, lp_share=model.lp_share,
-        gamma=model.gamma, n_servers=model.n_servers,
-        power_scale=model.power_scale)
-    with jax.enable_x64(True):
-        out = kops.polca_tick(
-            jnp.asarray(occ_ntr), jnp.asarray(model.budget_scale),
-            jnp.asarray(model.row_budget_w), consts=consts,
-            oob_ticks=model.oob_ticks, brake_ticks=model.brake_ticks,
-            ring_depth=model.ring_depth, esc=model.escalation_ticks)
-        row_w = np.asarray(out["row_w"], dtype=np.float64)  # [N, T, R]
-        fire = np.asarray(out["fire"], dtype=bool)
-        f_lp = np.asarray(out["f_lp"], dtype=np.float64)
-        f_hp = np.asarray(out["f_hp"], dtype=np.float64)
-        nbr = np.asarray(out["n_brakes"], dtype=np.int64)
-    frac = row_w.sum(axis=2) / model.total_budget_w  # [N, T]
-    backlog_hp = np.zeros((N, R))
-    backlog_lp = np.zeros((N, R))
-    imp_hp = np.zeros((N, R, model.n_slots))
-    imp_lp = np.zeros((N, R, model.n_slots))
-    for k in range(T):
-        backlog_hp, backlog_lp, ih, il = _slo_step(
-            model, occ_ntr[:, k], f_lp[:, k], f_hp[:, k],
-            backlog_hp, backlog_lp, np)
-        if k % model.stride == 0:
-            imp_hp[:, :, k // model.stride] = ih
-            imp_lp[:, :, k // model.stride] = il
-    run = BatchedRun(
-        engine="pallas", model=model, brake_fire=fire, n_brakes=nbr,
-        peak_frac=frac.max(axis=1), mean_frac=frac.mean(axis=1),
-        impacts_hp=imp_hp, impacts_lp=imp_lp)
-    if keep_series:
-        run.total_frac = frac
-        run.row_w = row_w
-        if model.node_matrix is not None:
-            run.node_w = np.einsum("ntr,mr->ntm", row_w, model.node_matrix)
-    return run
-
-
 # ---------------------------------------------------------------------------
 # entry points
 # ---------------------------------------------------------------------------
@@ -1259,18 +1221,16 @@ def run_tick_model(model: TickModel, members: List[Scenario], *,
                    mesh=None) -> BatchedRun:
     """Run a lowered tick program on one backend. ``engine="numpy"`` is the
     oracle (real policy objects through Telemetry); ``engine="jax"`` the
-    vectorized device program; ``engine="pallas"`` the kernel backend
-    (non-predictive policies). Differential tests run oracle + device
-    backends on the same model and compare."""
+    vectorized device program. Differential tests run both on the same
+    model and compare."""
     if engine == "numpy":
         return _run_oracle(model, members, keep_series)
     if engine == "jax":
-        return _run_jax(model, keep_series, keep_fire=keep_brake_fire,
-                        member_chunk=member_chunk, mesh=mesh)
-    if engine == "pallas":
-        return _run_pallas(model, keep_series)
+        return _run_jax_models([model], keep_series=keep_series,
+                               keep_fire=keep_brake_fire,
+                               member_chunk=member_chunk, mesh=mesh)[0]
     raise ValueError(f"unknown batched engine {engine!r} "
-                     "(expected 'numpy', 'jax', or 'pallas')")
+                     "(expected 'numpy' or 'jax')")
 
 
 def run_tick_models(models: Sequence[TickModel], *,
@@ -1283,8 +1243,8 @@ def run_tick_models(models: Sequence[TickModel], *,
 
     This is the model-level grid entry — :func:`run_batched_grid` lowers
     specs, buckets them by :func:`_geometry_key`, and lands here. It is
-    jax-engine only: the oracle and Pallas backends have no scenario axis
-    and run per model via :func:`run_tick_model`."""
+    jax-engine only: the oracle has no scenario axis and runs per model
+    via :func:`run_tick_model`."""
     return _run_jax_models(list(models), keep_series=keep_series,
                            keep_fire=keep_brake_fire,
                            member_chunk=member_chunk, mesh=mesh)
@@ -1385,8 +1345,6 @@ def run_batched_ensemble(spec: EnsembleSpec, *,
     ~512-member blocks once the run is big enough; ``0`` = flat vmap);
     ``mesh`` shards the member axis over a "data" mesh axis
     (``launch.mesh.data_mesh``)."""
-    if engine == "batched-numpy":  # run_ensemble's name for the tick oracle
-        engine = "numpy"
     with get_recorder().span("mc/run_batched", base=spec.base.name,
                              members=spec.n_seeds, engine=engine):
         model, members, budget = lower_ensemble(spec, budget_w=budget_w)
@@ -1417,12 +1375,9 @@ def run_batched_grid(specs: Sequence[EnsembleSpec], *,
     telemetry) is one bucket, so a 6-family CVaR frontier is one jit call.
     Results come back in spec order, one EnsembleResult per spec.
 
-    ``engine="numpy"``/``"pallas"`` fall back to a per-scenario loop (the
-    oracle is the reference semantics; the kernel recompiles per scenario
-    by design) — the grid API stays engine-agnostic for differential
-    tests."""
-    if engine == "batched-numpy":
-        engine = "numpy"
+    ``engine="numpy"`` falls back to a per-scenario loop (the oracle is the
+    reference semantics) — the grid API stays engine-agnostic for
+    differential tests."""
     lowered = [lower_ensemble(s, budget_w=budget_w) for s in specs]
     with get_recorder().span("mc/run_grid", scenarios=len(specs),
                              members=sum(m.n_members for m, _, _ in lowered),

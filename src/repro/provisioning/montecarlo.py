@@ -551,6 +551,11 @@ def resolve_ensemble_budget(base: Scenario) -> float:
     return float(budget)
 
 
+# run_ensemble's batched engine names -> run_batched_ensemble's: there the
+# tick oracle is "numpy", here "numpy" is the event-driven fork pool
+_BATCHED_ENGINES = {"jax": "jax", "batched-numpy": "numpy"}
+
+
 def run_ensemble(spec: EnsembleSpec, *, budget_w: Optional[float] = None,
                  engine: str = "numpy", **engine_opts) -> EnsembleResult:
     """Evaluate all members of ``spec`` in one batched pass.
@@ -565,22 +570,21 @@ def run_ensemble(spec: EnsembleSpec, *, budget_w: Optional[float] = None,
       tick-level lowering that runs 10^5+ members in one call;
     * ``"batched-numpy"`` — the numpy tick-level oracle of that same
       lowering (drives the real policy objects), used by the parity
-      harness;
-    * ``"pallas"`` — the Pallas tick kernel backend
-      (:mod:`repro.kernels.tick`, non-predictive policies).
+      harness; ``run_batched_ensemble`` calls it ``"numpy"``.
 
     ``engine_opts`` forward to ``run_batched_ensemble`` (``member_chunk``,
     ``mesh``, ``member_stats``, ``keep_series``, ``keep_brake_fire``);
     they are meaningless for the event-driven engine and rejected there.
     """
-    if engine in ("jax", "batched-numpy", "pallas"):
+    if engine in _BATCHED_ENGINES:
         from repro.provisioning.batched import run_batched_ensemble
-        return run_batched_ensemble(spec, budget_w=budget_w, engine=engine,
+        return run_batched_ensemble(spec, budget_w=budget_w,
+                                    engine=_BATCHED_ENGINES[engine],
                                     **engine_opts)
     if engine != "numpy":
         raise ValueError(
             f"unknown ensemble engine {engine!r}; "
-            "expected 'numpy', 'jax', 'batched-numpy', or 'pallas'")
+            "expected 'numpy', 'jax', or 'batched-numpy'")
     if engine_opts:
         raise ValueError(
             f"engine options {sorted(engine_opts)} only apply to the "
@@ -606,7 +610,7 @@ def run_ensemble_grid(bases: Sequence[Scenario], *, n_seeds: int = 8,
     ``engine="numpy"`` (default) flattens all M*N members into a single
     work list, shards it across the fork pool together, and re-groups into
     one :class:`EnsembleResult` per base scenario. The batched engines
-    (``"jax"``/``"batched-numpy"``/``"pallas"``) dispatch to
+    (``"jax"``/``"batched-numpy"``) dispatch to
     :func:`repro.provisioning.batched.run_batched_grid`, which buckets
     scenarios by tick geometry and runs each bucket as ONE scenario-axis
     vmapped device program — an M-family CVaR frontier is a single jit
@@ -615,15 +619,16 @@ def run_ensemble_grid(bases: Sequence[Scenario], *, n_seeds: int = 8,
     specs = [EnsembleSpec(b, n_seeds=n_seeds, seed0=seed0,
                           n_workers=n_workers,
                           lockstep_stride_s=lockstep_stride_s) for b in bases]
-    if engine in ("jax", "batched-numpy", "pallas"):
+    if engine in _BATCHED_ENGINES:
         from repro.provisioning.batched import run_batched_grid
-        results = run_batched_grid(specs, budget_w=budget_w, engine=engine,
+        results = run_batched_grid(specs, budget_w=budget_w,
+                                   engine=_BATCHED_ENGINES[engine],
                                    **engine_opts)
         return {s.base.name: r for s, r in zip(specs, results)}
     if engine != "numpy":
         raise ValueError(
             f"unknown ensemble engine {engine!r}; "
-            "expected 'numpy', 'jax', 'batched-numpy', or 'pallas'")
+            "expected 'numpy', 'jax', or 'batched-numpy'")
     if engine_opts:
         raise ValueError(
             f"engine options {sorted(engine_opts)} only apply to the "

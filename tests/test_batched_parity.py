@@ -13,6 +13,8 @@ stays fast while still crossing T1/T2 and (at high ``power_scale``) the
 brake threshold; every example still runs the full two-engine round trip.
 """
 
+from typing import NamedTuple
+
 import numpy as np
 import pytest
 
@@ -26,10 +28,13 @@ from conftest import (
 )
 
 from repro.chaos.faults import FaultEvent, FaultSpec
+from repro.core.policy import PolcaPolicy
+from repro.core.telemetry import Telemetry
 from repro.experiments.scenario import HierarchySpec
 from repro.provisioning.batched import (
     lower_ensemble,
     run_batched_ensemble,
+    run_batched_grid,
     run_tick_model,
     split_run,
     stack_tick_models,
@@ -38,6 +43,7 @@ from repro.provisioning.montecarlo import (
     EnsembleSpec,
     resolve_ensemble_budget,
     run_ensemble,
+    run_ensemble_grid,
 )
 from repro.provisioning.planner import RiskConstraints, plan_capacity
 
@@ -399,6 +405,145 @@ def test_quiet_scenario_is_quiet_on_both_engines():
         assert run.peak_frac.max() < 1.0
         assert np.abs(run.impacts_hp).max() < 1e-9
     assert_engine_parity(oracle, jaxed)
+
+
+# ---------------------------------------------------------------------------
+# the scan's latch step, against the policy objects it vectorizes
+# ---------------------------------------------------------------------------
+
+class _LatchConsts(NamedTuple):
+    """The ``_Consts`` fields the latch step reads."""
+
+    t1: float = 0.90
+    t2: float = 0.97
+    t1_buf: float = 0.02
+    t2_buf: float = 0.02
+    lp_t1: float = 0.85
+    lp_t2: float = 0.70
+    hp_t2: float = 0.85
+
+
+def _run_latch_step(power, esc, c=_LatchConsts()):
+    """Drive ``batched.polca_latch_step`` as the scan does (jax, float64)
+    over a ``[T, N, R]`` power trace; returns the per-tick fire and command
+    planes and the final latches, as numpy."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.provisioning import batched
+
+    with jax.enable_x64(True):
+        step = jax.jit(lambda lat, p, c: batched.polca_latch_step(
+            lat, p, p, 0.4 * p, c, jnp, esc=esc, predictive=False))
+        shape = power.shape[1:]
+        lat = batched.PolcaLatches(
+            *(jnp.zeros(shape, bool) for _ in range(4)),
+            t2s=jnp.zeros(shape, jnp.int32))
+        planes = []
+        for p in power:
+            lat, fire, lp_cmd, hp_cmd = step(lat, jnp.asarray(p), c)
+            planes.append([np.asarray(a) for a in (fire, lp_cmd, hp_cmd)])
+    fire, lp_cmd, hp_cmd = (np.stack(a) for a in zip(*planes))
+    return fire, lp_cmd, hp_cmd, [np.asarray(a) for a in lat]
+
+
+LATCH_CASES = [
+    # (N, T, R, esc, power_scale)
+    (8, 96, 2, 25, 1.10),
+    (5, 96, 2, 25, 1.10),
+    (13, 64, 3, 25, 1.18),  # hot: brakes fire
+    (3, 48, 1, 4, 1.05),    # fast escalation
+    (16, 32, 2, 25, 0.95),  # cool: mostly uncapped
+]
+
+
+@pytest.mark.parametrize("case", LATCH_CASES,
+                         ids=lambda c: f"n{c[0]}t{c[1]}r{c[2]}e{c[3]}ps{c[4]}")
+def test_latch_step_matches_policy_observe(case):
+    """Each tick, the latch step's brake firings and LP/HP command planes
+    equal N x R real ``PolcaPolicy`` objects fed the same power through
+    ``Telemetry``, bit for bit: a plane is NaN where the policy issues no
+    command, else its command list folded field by field, later commands
+    overwriting earlier ones (brake commands are the fire plane)."""
+    N, T, R, esc, ps = case
+    rng = np.random.default_rng(N * 1000 + T)
+    k = np.arange(T)[:, None, None]
+    wave = 0.87 + 0.09 * np.sin(2 * np.pi * k / rng.uniform(20, 60, (N, R))
+                                + rng.uniform(0, 2 * np.pi, (N, R)))
+    power = ps * (wave + rng.normal(0.0, 0.01, (T, N, R)))  # [T, N, R]
+    fire, lp_cmd, hp_cmd, _ = _run_latch_step(power, esc)
+
+    c = _LatchConsts()
+    policies = [[PolcaPolicy(t1=c.t1, t2=c.t2, t1_buffer=c.t1_buf,
+                             t2_buffer=c.t2_buf, lp_freq_t1=c.lp_t1,
+                             lp_freq_t2=c.lp_t2, hp_freq_t2=c.hp_t2,
+                             escalation_ticks=esc)
+                 for _ in range(R)] for _ in range(N)]
+    want_fire = np.zeros((T, N, R), bool)
+    want_lp = np.full((T, N, R), np.nan)
+    want_hp = np.full((T, N, R), np.nan)
+    for t in range(T):
+        for n in range(N):
+            for r in range(R):
+                pol = policies[n][r]
+                before = pol.n_brakes
+                cmds = pol.observe(Telemetry(
+                    t=2.0 * (t + 1), power_frac=float(power[t, n, r]),
+                    lp_power_frac=float(0.4 * power[t, n, r]), row_index=r))
+                want_fire[t, n, r] = pol.n_brakes > before
+                for cmd in cmds:
+                    if cmd.brake:
+                        continue
+                    if cmd.lp_freq is not None:
+                        want_lp[t, n, r] = cmd.lp_freq
+                    if cmd.hp_freq is not None:
+                        want_hp[t, n, r] = cmd.hp_freq
+    np.testing.assert_array_equal(fire, want_fire)
+    np.testing.assert_array_equal(lp_cmd, want_lp)
+    np.testing.assert_array_equal(hp_cmd, want_hp)
+    assert np.isfinite(want_lp).any(), "the trace issued no command"
+
+
+def test_latch_step_fires_and_releases_brakes():
+    """A hand-worked trace on row 0 (row 1 stays idle): a brake fires once
+    per overload, its release falls back to the T2 caps, and a drop below
+    T1 - buffer releases T2 and then T1 in the same tick."""
+    power = np.array([0.5, 0.5, 1.05, 1.05, 0.99, 0.5, 1.02, 0.5])
+    power = np.stack([power, np.full(8, 0.5)], axis=1)[:, None, :]  # [T, 1, 2]
+    fire, lp_cmd, hp_cmd, latches = _run_latch_step(power, esc=25)
+    nan = np.nan
+    np.testing.assert_array_equal(np.flatnonzero(fire[:, 0, 0]), [2, 6])
+    np.testing.assert_array_equal(
+        lp_cmd[:, 0, 0], [nan, nan, nan, nan, 0.70, 1.0, nan, 1.0])
+    np.testing.assert_array_equal(
+        hp_cmd[:, 0, 0], [nan, nan, nan, nan, 0.85, 1.0, nan, 1.0])
+    assert not fire[:, 0, 1].any()
+    assert np.isnan(lp_cmd[:, 0, 1]).all() and np.isnan(hp_cmd[:, 0, 1]).all()
+    for a in latches:
+        assert not a.any()
+
+
+# every entry that takes an engine name, given one that does not exist
+REFUSING_ENTRIES = {
+    "run_tick_model": lambda spec: run_tick_model(
+        *lower_ensemble(spec)[:2], engine="pallas"),
+    "run_batched_ensemble": lambda spec: run_batched_ensemble(
+        spec, engine="pallas"),
+    "run_batched_grid": lambda spec: run_batched_grid([spec],
+                                                      engine="pallas"),
+    "run_ensemble": lambda spec: run_ensemble(spec, engine="pallas"),
+    "run_ensemble_grid": lambda spec: run_ensemble_grid(
+        [spec.base], n_seeds=2, engine="pallas"),
+}
+
+
+@pytest.mark.parametrize("entry", REFUSING_ENTRIES.values(),
+                         ids=REFUSING_ENTRIES.keys())
+def test_removed_engine_is_refused(entry):
+    spec = EnsembleSpec(parity_scenario(duration_s=600.0), n_seeds=2)
+    with pytest.raises(ValueError, match="engine 'pallas'") as err:
+        entry(spec)
+    assert "'numpy'" in str(err.value) and "'jax'" in str(err.value)
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """chip_smoke.py's phases at smoke size on the host CPU, and the process
 hygiene the chip run relies on: one fixed compile-cache path, no fork pool
-once a chip is held, and a clear refusal of the float64 kernel on TPU.
+once a chip is held.
 
 ``main()`` alone demands a TPU; the phase functions run anywhere."""
 
@@ -95,16 +95,3 @@ def test_holds_accelerator_false_on_cpu():
 
     jax.devices()
     assert not montecarlo._holds_accelerator()
-
-
-def test_pallas_engine_refuses_float64_on_tpu(monkeypatch):
-    from repro.provisioning import batched
-    from repro.provisioning.montecarlo import EnsembleSpec
-    from conftest import parity_scenario
-
-    model, _, _ = batched.lower_ensemble(
-        EnsembleSpec(parity_scenario(duration_s=600.0), n_seeds=2))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(NotImplementedError, match="float64"):
-        batched._run_pallas(model, keep_series=False)
-

@@ -15,6 +15,7 @@ from conftest import (
     PARITY_GENERATORS,
     assert_engine_parity,
     parity_scenario,
+    run_both_engines,
 )
 from repro.launch.mesh import data_mesh
 from repro.provisioning.batched import (
@@ -23,7 +24,6 @@ from repro.provisioning.batched import (
     run_batched_ensemble,
     run_batched_grid,
     run_tick_model,
-    stack_tick_models,
 )
 from repro.provisioning.montecarlo import (
     EnsembleSpec,
@@ -161,22 +161,14 @@ def test_plan_capacity_probe_count_does_not_multiply_compiles(lanes,
 
 
 @pytest.mark.parametrize("generator", PARITY_GENERATORS)
-def test_pallas_engine_parity(generator):
-    """The Pallas tick kernel backend satisfies the same oracle contract as
-    the scan engine: brake sets bit-identical, power within 1e-6 relative."""
-    model, members, _ = lower_ensemble(
-        EnsembleSpec(parity_scenario(generator=generator), n_seeds=3))
-    oracle = run_tick_model(model, members, engine="numpy")
-    pallas = run_tick_model(model, members, engine="pallas")
-    assert pallas.engine == "pallas"
-    assert_engine_parity(oracle, pallas)
-
-
-def test_pallas_rejects_predictive():
-    model, members, _ = lower_ensemble(EnsembleSpec(
-        parity_scenario(policy="polca-predictive"), n_seeds=2))
-    with pytest.raises(ValueError, match="predictive"):
-        run_tick_model(model, members, engine="pallas")
+def test_scan_engine_parity(generator):
+    """The scan engine satisfies the oracle contract on each generator
+    family's harness scenario: brake sets bit-identical, power within 1e-6
+    relative."""
+    _, oracle, jaxed = run_both_engines(parity_scenario(generator=generator),
+                                        n_seeds=3)
+    assert jaxed.engine == "jax"
+    assert_engine_parity(oracle, jaxed)
 
 
 def test_plan_capacity_rounds_invariant_to_mesh():
@@ -192,17 +184,6 @@ def test_plan_capacity_rounds_invariant_to_mesh():
         [p.added_servers for p in one.probes]
     for a, b in zip(four.probes, one.probes):
         _assert_results_identical(a.ensemble, b.ensemble)
-
-
-def test_pallas_rejects_stacked_model():
-    sc = parity_scenario()
-    models = [lower_ensemble(EnsembleSpec(sc.with_fleet(added_frac=f),
-                                          n_seeds=2), budget_w=1e6)
-              for f in (0.0, 0.1)]
-    stacked = stack_tick_models([m for m, _, _ in models])
-    with pytest.raises(ValueError, match="candidate fleets"):
-        run_tick_model(stacked, models[0][1] + models[1][1],
-                       engine="pallas")
 
 
 def test_dense_member_stats_equivalent():

@@ -68,74 +68,6 @@ def test_decode_attention_vs_ref(case):
     np.testing.assert_allclose(np.float32(got), np.float32(want), atol=3e-2, rtol=3e-2)
 
 
-TICK_CONSTS = dict(t1=0.90, t2=0.97, t1_buf=0.02, t2_buf=0.02,
-                   lp_t1=0.85, lp_t2=0.70, hp_t2=0.85, brake_freq=0.50,
-                   p0_srv_w=180.0, k_lp_w=300.0, k_hp_w=150.0,
-                   lp_share=0.6, gamma=1.6, n_servers=24.0,
-                   power_scale=1.10)
-
-TICK_CASES = [
-    # (N, T, R, block_members, oob, brake, esc, power_scale, block_ticks)
-    (8, 96, 2, 8, 20, 3, 25, 1.10, 32),   # three time blocks
-    (5, 96, 2, 8, 20, 3, 25, 1.10, 40),   # N, T not block multiples (padding)
-    (13, 64, 3, 4, 20, 3, 25, 1.18, 64),  # hot: brakes fire
-    (3, 48, 1, 8, 5, 2, 4, 1.05, 7),      # short ring, fast escalation
-    (16, 32, 2, 16, 20, 3, 25, 0.95, 256),  # cool: mostly uncapped
-]
-
-
-@pytest.mark.parametrize("case", TICK_CASES,
-                         ids=lambda c: f"n{c[0]}t{c[1]}r{c[2]}b{c[3]}ps{c[7]}")
-def test_polca_tick_vs_ref(case):
-    """Pallas tick kernel vs the shared-step lax.scan reference: power plane
-    to 1e-6 relative, brake/frequency planes bit-identical (float64)."""
-    from repro.kernels.tick import TickConsts
-
-    N, T, R, bm, oob, brake, esc, ps, tb = case
-    ring_depth = max(oob, brake) + 1
-    consts = TickConsts(**{**TICK_CONSTS, "power_scale": ps})
-    with jax.enable_x64(True):
-        rng = np.random.default_rng(N * 1000 + T)
-        occ = jnp.asarray(rng.uniform(0.3, 1.0, (N, T, R)))
-        bscale = jnp.asarray(rng.uniform(0.9, 1.0, (T, R)))
-        row_budget = jnp.asarray(
-            consts.n_servers * (consts.p0_srv_w + 0.8 * consts.k_lp_w)
-            * np.ones(R))
-        got = ops.polca_tick(occ, bscale, row_budget, consts=consts,
-                             oob_ticks=oob, brake_ticks=brake,
-                             ring_depth=ring_depth, esc=esc,
-                             block_members=bm, block_ticks=tb,
-                             interpret=True)
-        want = ref.polca_tick_reference(occ, bscale, row_budget, consts,
-                                        oob_ticks=oob, brake_ticks=brake,
-                                        ring_depth=ring_depth, esc=esc)
-    np.testing.assert_array_equal(np.asarray(got["fire"]),
-                                  np.asarray(want["fire"]))
-    np.testing.assert_array_equal(np.asarray(got["n_brakes"]),
-                                  np.asarray(want["n_brakes"]))
-    for k in ("f_lp", "f_hp"):
-        np.testing.assert_array_equal(np.asarray(got[k]),
-                                      np.asarray(want[k]), err_msg=k)
-    np.testing.assert_allclose(np.asarray(got["row_w"]),
-                               np.asarray(want["row_w"]),
-                               rtol=1e-6, atol=0.0)
-
-
-def test_polca_tick_brakes_actually_fire():
-    """The hot case must exercise the brake path (otherwise the parity above
-    proves nothing about rings/latches)."""
-    from repro.kernels.tick import TickConsts
-
-    consts = TickConsts(**{**TICK_CONSTS, "power_scale": 1.30})
-    with jax.enable_x64(True):
-        occ = jnp.ones((4, 64, 2)) * 0.98
-        out = ops.polca_tick(occ, jnp.ones((64, 2)),
-                             jnp.full(2, consts.n_servers * 250.0),
-                             consts=consts, oob_ticks=20, brake_ticks=3,
-                             ring_depth=21, esc=25, interpret=True)
-    assert int(np.asarray(out["n_brakes"]).sum()) > 0
-
-
 def test_flash_matches_model_xla_path():
     """Kernel and the model's XLA attention path agree on identical inputs."""
     from repro.models.attention import _chunk_scores, _make_mask

@@ -214,10 +214,3 @@ def test_ensemble_and_plan_carry_the_node_stats():
     for p in plan.probes:
         assert p.ensemble.node_over_ticks.shape == (2, 7)
         assert p.ensemble.node_names == ens.node_names
-
-
-def test_pallas_refuses_a_rated_tree():
-    from repro.provisioning.batched import run_batched_ensemble
-
-    with pytest.raises(ValueError, match="rated budget tree"):
-        run_batched_ensemble(_spec(site_scenario(), n=2), engine="pallas")

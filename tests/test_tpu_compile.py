@@ -79,32 +79,6 @@ def test_scan_engine_compiles_for_v5e(one_chip):
     assert _program_bytes(compiled) < V5E_HBM_BYTES
 
 
-def test_tick_kernel_compiles_for_v5e_float32(one_chip):
-    """The Pallas tick kernel lowers through Mosaic in float32 at 12 rows,
-    10,800 ticks and 8-member blocks. Members are cut to 64: rows sit on the
-    lane axis, so each [T, N, R] plane pads 12 lanes to 128 in HBM, and
-    4,096 members would need 23 GB per plane."""
-    from repro.kernels import ops
-    from repro.kernels.tick import TickConsts
-
-    consts = TickConsts(t1=0.90, t2=0.97, t1_buf=0.02, t2_buf=0.02,
-                        lp_t1=0.85, lp_t2=0.70, hp_t2=0.85, brake_freq=0.50,
-                        p0_srv_w=180.0, k_lp_w=300.0, k_hp_w=150.0,
-                        lp_share=0.6, gamma=1.6, n_servers=24.0,
-                        power_scale=1.10)
-    n = 64
-    args = (jax.ShapeDtypeStruct((n, N_TICKS, N_ROWS), jnp.float32,
-                                 sharding=one_chip),
-            jax.ShapeDtypeStruct((N_TICKS, N_ROWS), jnp.float32,
-                                 sharding=one_chip),
-            jax.ShapeDtypeStruct((N_ROWS,), jnp.float32, sharding=one_chip))
-    compiled = ops.polca_tick.lower(
-        *args, consts=consts, oob_ticks=20, brake_ticks=3, ring_depth=21,
-        esc=25, block_members=8, interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    assert _program_bytes(compiled) < V5E_HBM_BYTES
-
-
 def test_llama_prefill_compiles_for_v5e(topo):
     """The serve path's prefill step for llama3.2-1b at full width (16
     layers, d_model 2048, vocab 128,256) on a one-chip mesh with Auto axes:

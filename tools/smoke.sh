@@ -35,7 +35,7 @@ python -m benchmarks.run --quick --only observability
 echo "== alerting quick benchmark =="
 python -m benchmarks.run --quick --only alerting
 
-echo "== batched-engine quick benchmark (grid engine + kernel parity + tails) =="
+echo "== batched-engine quick benchmark (grid engine + oracle parity + tails) =="
 # forced host devices exercise the sharded member axis; the grid rows record
 # members/sec trajectory into BENCH_batched_engine.json via --artifacts below
 ARTIFACTS_DIR="${ARTIFACTS_DIR:-out/smoke-artifacts}"

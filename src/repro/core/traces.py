@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Tuple
+from typing import Callable, Dict, FrozenSet, Iterable, List, Tuple
 
 import numpy as np
 
@@ -143,22 +143,34 @@ def mape(a: np.ndarray, b: np.ndarray) -> float:
 # the experiment runner dispatches through this registry so scenario families
 # (bursty, colocated, failover, ...) plug in without the runner knowing them.
 # The families themselves live in ``repro.provisioning.ensembles`` and
-# register here on import; only "diurnal" is built in.
+# register here on import; only "diurnal" is built in. A generator also
+# declares which of the per-member arguments, ``seed`` and ``row``, change
+# its curve (``varies_with``, both unless it says less): the batched lowering
+# calls it once per distinct value of those, not once per member and row.
 
 OccupancyGenerator = Callable[..., np.ndarray]
 
-_OCC_GENERATORS: Dict[str, OccupancyGenerator] = {}
+# name -> (generator, the per-member arguments its curve varies with)
+_OCC_GENERATORS: Dict[str, Tuple[OccupancyGenerator, FrozenSet[str]]] = {}
+_MEMBER_ARGS = frozenset({"seed", "row"})
 
 
 def register_occupancy_generator(name: str, gen: OccupancyGenerator, *,
-                                 overwrite: bool = False) -> OccupancyGenerator:
+                                 overwrite: bool = False,
+                                 varies_with: Iterable[str] = ("seed", "row"),
+                                 ) -> OccupancyGenerator:
+    varies = frozenset(varies_with)
+    if not varies <= _MEMBER_ARGS:
+        raise ValueError(
+            f"occupancy generator {name!r}: varies_with may name only "
+            f"'seed' and 'row', got {sorted(varies - _MEMBER_ARGS)}")
     if name in _OCC_GENERATORS and not overwrite:
         raise ValueError(f"occupancy generator {name!r} already registered")
-    _OCC_GENERATORS[name] = gen
+    _OCC_GENERATORS[name] = (gen, varies)
     return gen
 
 
-def get_occupancy_generator(name: str) -> OccupancyGenerator:
+def _occupancy_entry(name: str) -> Tuple[OccupancyGenerator, FrozenSet[str]]:
     try:
         return _OCC_GENERATORS[name]
     except KeyError:
@@ -167,6 +179,16 @@ def get_occupancy_generator(name: str) -> OccupancyGenerator:
             f"unknown occupancy generator {name!r}; registered: {known}. "
             "The scenario families register on `import repro.provisioning`."
         ) from None
+
+
+def get_occupancy_generator(name: str) -> OccupancyGenerator:
+    return _occupancy_entry(name)[0]
+
+
+def occupancy_generator_varies(name: str) -> FrozenSet[str]:
+    """The per-member arguments (``seed``, ``row``) that change the curve of
+    the generator registered as ``name``."""
+    return _occupancy_entry(name)[1]
 
 
 def list_occupancy_generators() -> List[str]:
@@ -183,7 +205,7 @@ def _diurnal_generator(t_grid: np.ndarray, *, seed: int = 1, peak: float = 0.62,
     return occupancy_curve(t_grid, peak=peak, **kw)
 
 
-register_occupancy_generator("diurnal", _diurnal_generator)
+register_occupancy_generator("diurnal", _diurnal_generator, varies_with=())
 
 
 # ---------------------------------------------------------------------------

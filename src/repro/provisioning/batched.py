@@ -76,7 +76,11 @@ from repro.core.policy import PolcaPolicy, PredictivePolcaPolicy
 from repro.core.simulator import SimResult
 from repro.core.slo import LatencyStats
 from repro.core.telemetry import Telemetry
-from repro.core.traces import TABLE4, get_occupancy_generator
+from repro.core.traces import (
+    TABLE4,
+    get_occupancy_generator,
+    occupancy_generator_varies,
+)
 from repro.experiments.runner import build_workloads, row_budgets
 from repro.experiments.scenario import Scenario
 from repro.obs.metrics import get_recorder
@@ -316,7 +320,8 @@ def _power_constants(sc: Scenario) -> Dict[str, float]:
 
 # base generator curves are independent of fleet size (only the CLT jitter
 # scales with n_servers), so a plan_capacity bisection — which re-lowers per
-# probe because fleets differ — reuses them across every probe
+# probe because fleets differ — reuses them across every probe. A curve that
+# depends on neither seed nor row (diurnal) is one entry for every lowering.
 _BASE_OCC_CACHE: Dict[tuple, np.ndarray] = {}
 # sized for a 4-generator x 10^3-seed x 2-row grid with headroom; entries
 # are short 60 s-grid curves (a few KB each), so the cap is ~100 MB worst
@@ -330,29 +335,59 @@ def _member_occupancy(sc: Scenario, seeds: Sequence[int], t60: np.ndarray,
     seed + row, plus a member-seeded CLT busy-fraction jitter
     (sigma = sqrt(occ(1-occ)/n_servers)) standing in for the arrival-sampling
     noise of the DES — without it the diurnal family (which deliberately
-    ignores the member seed) would collapse every member onto one curve."""
-    gen = get_occupancy_generator(sc.traffic.generator)
-    gkey = (sc.traffic.generator, len(t60),
-            float(t60[-1]) if len(t60) else 0.0,
+    ignores the member seed) would collapse every member onto one curve.
+
+    The generator runs once per distinct value of the arguments it declares
+    it varies with (``occupancy_generator_varies``), so diurnal's one curve
+    and its sigma serve every member and row. Each member-row draws its
+    normals from its own ``default_rng([seed, row, _JITTER_SALT])``; the
+    scale, add and clip then run once per member slab, in the per-row
+    order, so every element is the same float64 as a per-row loop gives.
+    Counts ``batched_occupancy_curves_total`` (generator calls, the cache's
+    misses) and ``batched_occupancy_draws_total`` (member-row draws)."""
+    name = sc.traffic.generator
+    gen = get_occupancy_generator(name)
+    varies = occupancy_generator_varies(name)
+    by_seed, by_row = "seed" in varies, "row" in varies
+    gkey = (name, len(t60), float(t60[-1]) if len(t60) else 0.0,
             float(sc.traffic.occ_peak), n_rows,
             tuple(sorted((k, repr(v))
                          for k, v in sc.traffic.gen_params.items())))
+    curves = 0
+
+    def curve(seed: int, r: int) -> np.ndarray:
+        nonlocal curves
+        ck = gkey + (seed if by_seed else None, r if by_row else None)
+        base = _BASE_OCC_CACHE.get(ck)
+        if base is None:
+            base = np.asarray(
+                gen(t60, seed=seed, peak=sc.traffic.occ_peak, n_rows=n_rows,
+                    row=r, **sc.traffic.gen_params), dtype=np.float64)
+            curves += 1
+            if len(_BASE_OCC_CACHE) < _BASE_OCC_CACHE_MAX:
+                _BASE_OCC_CACHE[ck] = base
+        return base
+
     occ = np.empty((len(seeds), n_rows, len(t60)), dtype=np.float64)
     for mi, seed in enumerate(seeds):
+        seed = int(seed)
+        slab = occ[mi]
         for r in range(n_rows):
-            ck = gkey + (int(seed), r)
-            base = _BASE_OCC_CACHE.get(ck)
-            if base is None:
-                base = np.asarray(
-                    gen(t60, seed=int(seed), peak=sc.traffic.occ_peak,
-                        n_rows=n_rows, row=r, **sc.traffic.gen_params),
-                    dtype=np.float64)
-                if len(_BASE_OCC_CACHE) < _BASE_OCC_CACHE_MAX:
-                    _BASE_OCC_CACHE[ck] = base
-            rng = np.random.default_rng([int(seed), r, _JITTER_SALT])
-            sigma = np.sqrt(np.clip(base * (1.0 - base), 0.0, None) / n_servers)
-            occ[mi, r] = np.clip(base + rng.standard_normal(len(t60)) * sigma,
-                                 0.0, 1.0)
+            np.random.default_rng([seed, r, _JITTER_SALT]).standard_normal(
+                out=slab[r])
+        if by_seed or mi == 0:  # else the first member's base and sigma
+            base = (np.stack([curve(seed, r) for r in range(n_rows)])
+                    if by_row else curve(seed, 0))
+            sigma = np.sqrt(np.clip(base * (1.0 - base), 0.0, None)
+                            / n_servers)
+        slab *= sigma
+        slab += base
+        np.clip(slab, 0.0, 1.0, out=slab)
+    rec = get_recorder()
+    if rec.enabled:
+        rec.counter("batched_occupancy_curves_total", float(curves))
+        rec.counter("batched_occupancy_draws_total",
+                    float(len(seeds) * n_rows))
     return occ
 
 

@@ -188,6 +188,82 @@ def test_member_batch_invariance(gen, seed0):
         np.testing.assert_array_equal(solo.impacts_lp[0], full.impacts_lp[m])
 
 
+def _occupancy_per_member_row(sc, seeds, t60, n_rows, n_servers):
+    """The lowering's occupancy written out as one generator call, one
+    jitter stream and one sigma, add and clip per member and row."""
+    from repro.core.traces import get_occupancy_generator
+
+    gen = get_occupancy_generator(sc.traffic.generator)
+    occ = np.empty((len(seeds), n_rows, len(t60)))
+    for mi, seed in enumerate(seeds):
+        for r in range(n_rows):
+            base = np.asarray(gen(t60, seed=int(seed), peak=sc.traffic.occ_peak,
+                                  n_rows=n_rows, row=r,
+                                  **sc.traffic.gen_params), dtype=np.float64)
+            rng = np.random.default_rng([int(seed), r, 9173])
+            sigma = np.sqrt(np.clip(base * (1.0 - base), 0.0, None) / n_servers)
+            occ[mi, r] = np.clip(base + rng.standard_normal(len(t60)) * sigma,
+                                 0.0, 1.0)
+    return occ
+
+
+def _bursty_varying_with(varies):
+    """Bursty's curve, at the member's seed and row where ``varies`` names
+    them and at seed 1, row 0 where it does not."""
+    from repro.core.traces import get_occupancy_generator
+
+    bursty = get_occupancy_generator("bursty")
+
+    def gen(t, *, seed=1, row=0, **kw):
+        return bursty(t, seed=seed if "seed" in varies else 1,
+                      row=row if "row" in varies else 0, **kw)
+
+    return gen
+
+
+# (generator, declared varies_with or None for a registered family, rows,
+#  generator calls a cold lowering of 5 members makes)
+_OCC_CASES = [
+    ("diurnal", None, 1, 1),
+    ("diurnal", None, 56, 1),
+    ("bursty", None, 2, 10),
+    ("seed-only", ("seed",), 3, 5),
+    ("row-only", ("row",), 3, 3),
+]
+
+
+@pytest.mark.parametrize("gen,varies,n_rows,curves", _OCC_CASES,
+                         ids=[f"{c[0]}-{c[2]}" for c in _OCC_CASES])
+def test_member_occupancy_equals_the_per_member_row_loop(gen, varies, n_rows,
+                                                         curves, monkeypatch):
+    """The lowering's occupancy is bit-equal to a per-member-row loop for
+    each declaration a generator can make: a curve that varies with neither
+    seed nor row (diurnal), with both (bursty), with the seed alone or with
+    the row alone. A cold cache calls the generator once per distinct curve;
+    a warm one, not at all."""
+    from repro.core import traces
+    from repro.obs.metrics import MetricsRecorder, recording
+    from repro.provisioning import batched
+
+    monkeypatch.setattr(batched, "_BASE_OCC_CACHE", {})
+    if varies is not None:
+        monkeypatch.setitem(
+            traces._OCC_GENERATORS, gen,
+            (_bursty_varying_with(varies), frozenset(varies)))
+    sc = parity_scenario(generator=gen, occ_peak=0.62)
+    t60 = np.arange(0.0, 3 * 3600.0, 60.0)
+    seeds = [7, 2**31 + 11, 40_000_001, 3, 2**32 + 5]
+    want = _occupancy_per_member_row(sc, seeds, t60, n_rows, 26)
+    for calls in (curves, 0):
+        rec = MetricsRecorder()
+        with recording(rec):
+            got = batched._member_occupancy(sc, seeds, t60, n_rows, 26)
+        assert got.shape == want.shape and np.array_equal(got, want)
+        assert rec.snapshot().counter_total(
+            "batched_occupancy_curves_total") == calls
+    assert not np.array_equal(got[0], got[1])
+
+
 def test_lowering_rejects_routed_and_short_scenarios():
     from repro.experiments.scenario import RoutingSpec
 

@@ -386,6 +386,52 @@ def test_batched_engine_bit_parity_recorder_on_vs_off():
              p.slo_violation_prob, p.peak_frac_max) for p in plan_off.probes]
 
 
+def test_occupancy_counters_count_curves_and_draws(monkeypatch):
+    """A lowering counts the generator's calls and the member-row jitter
+    draws: a diurnal curve is drawn once for every member and row, and not
+    again by a lowering with fresh seeds; a bursty one once per
+    member-row."""
+    import dataclasses
+
+    from repro.provisioning import batched
+    from repro.provisioning.batched import lower_ensemble
+
+    monkeypatch.setattr(batched, "_BASE_OCC_CACHE", {})
+    sc = _batched_scenario()
+
+    def counts(spec):
+        rec = MetricsRecorder()
+        with recording(rec):
+            lower_ensemble(spec)
+        snap = rec.snapshot()
+        return (snap.counter_total("batched_occupancy_curves_total"),
+                snap.counter_total("batched_occupancy_draws_total"))
+
+    assert sc.traffic.generator == "diurnal" and sc.fleet.n_rows == 2
+    assert counts(EnsembleSpec(sc, n_seeds=3, seed0=5)) == (1, 6)
+    assert counts(EnsembleSpec(sc, n_seeds=4, seed0=2**31 + 9)) == (0, 8)
+    bursty = sc.with_(traffic=dataclasses.replace(sc.traffic,
+                                                  generator="bursty"))
+    assert counts(EnsembleSpec(bursty, n_seeds=3, seed0=5)) == (6, 6)
+
+
+def test_occupancy_counters_for_a_cold_site_lowering(monkeypatch):
+    """The site's 256 members x 56 rows of diurnal occupancy call the
+    generator once and draw 14,336 jitter streams."""
+    from repro.provisioning import batched
+
+    monkeypatch.setattr(batched, "_BASE_OCC_CACHE", {})
+    sc = _batched_scenario()
+    rec = MetricsRecorder()
+    with recording(rec):
+        occ = batched._member_occupancy(
+            sc, range(256), np.arange(0.0, 600.0, 60.0), 56, 52)
+    snap = rec.snapshot()
+    assert occ.shape == (256, 56, 10)
+    assert snap.counter_total("batched_occupancy_curves_total") == 1
+    assert snap.counter_total("batched_occupancy_draws_total") == 256 * 56
+
+
 def test_rated_tree_fold_span_counter_and_bit_parity(monkeypatch):
     """A rated tree's dispatch labels ``batched/run`` with the nodes it
     folds, counts ``batched_node_fold_cells_total`` (members x ticks x

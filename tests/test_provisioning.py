@@ -8,6 +8,8 @@ from repro.core.slo import DEFAULT_SLO, SLO
 from repro.core.traces import (
     get_occupancy_generator,
     list_occupancy_generators,
+    occupancy_generator_varies,
+    register_occupancy_generator,
     replication_report,
 )
 from repro.experiments import (
@@ -71,6 +73,36 @@ def test_generator_rows_are_deterministic_per_row():
     r1 = gen(T_GRID, seed=3, peak=0.62, n_rows=4, row=1, rho=0.5)
     assert np.array_equal(r0, r0b)
     assert not np.array_equal(r0, r1), "rows must decorrelate at rho<1"
+
+
+@pytest.mark.parametrize("name", ["diurnal", "bursty", "colocated",
+                                  "failover-surge", "rack-incident",
+                                  "nighttime"])
+def test_generator_curve_ignores_what_it_does_not_vary_with(name):
+    """The batched lowering calls a generator once per value of what it
+    declares its curve varies with: diurnal declares nothing, the families
+    seed and row. A per-member argument left out of the declaration must
+    leave the curve bit-identical."""
+    varies = occupancy_generator_varies(name)
+    assert varies == (frozenset() if name == "diurnal"
+                      else frozenset({"seed", "row"}))
+    gen = get_occupancy_generator(name)
+    ref = gen(T_GRID, seed=3, peak=0.62, n_rows=4, row=1)
+    if "seed" not in varies:
+        assert np.array_equal(gen(T_GRID, seed=2**31 + 7, peak=0.62,
+                                  n_rows=4, row=1), ref)
+    if "row" not in varies:
+        assert np.array_equal(gen(T_GRID, seed=3, peak=0.62, n_rows=4,
+                                  row=3), ref)
+
+
+def test_generator_registry_refuses_an_unknown_member_argument():
+    with pytest.raises(ValueError, match="varies_with"):
+        register_occupancy_generator("never-registered", lambda t, **kw: t,
+                                     varies_with=("peak",))
+    assert "never-registered" not in list_occupancy_generators()
+    with pytest.raises(KeyError, match="never-registered"):
+        occupancy_generator_varies("never-registered")
 
 
 def test_rack_incident_zeroes_lost_rack_rows():
